@@ -5,16 +5,15 @@ log-likelihood after equal iterations, all on the shared substrate
 The sweep list IS the registry: a newly registered backend shows up here
 with zero benchmark changes — on BOTH axes: the single-box sweep below,
 and a mesh x backend sweep that times the distributed step for every
-``supports_shard_map`` backend on a simulated 2-device CPU mesh. Both
-axes drive the same ``TrainSession`` API (mesh_shape selects the plan),
-so what is timed is exactly what ``launch/train.py`` runs. The mesh
-cells run in a subprocess because the host device count locks at first
-jax init (same trick as tests/helpers.py)."""
+``supports_shard_map`` backend on a (1, 2) mesh. Both axes drive the same
+``TrainSession`` API (mesh_shape selects the plan), so what is timed is
+exactly what ``launch/train.py`` runs. The mesh axis runs in this process
+over the devices JAX already has; with fewer than two it is skipped with
+a printed reason (on CPU, start the run with
+``XLA_FLAGS=--xla_force_host_platform_device_count=2``). A failing cell
+raises: the run exits non-zero instead of recording an error row."""
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -25,68 +24,33 @@ from repro.core import LDAHyperParams
 from repro.data import synthetic_lda_corpus
 from repro.train.session import RunConfig, TrainSession
 
-_MESH_CHILD = """
-import warnings; warnings.filterwarnings('ignore')
-import time
-import jax
-from repro.data import synthetic_lda_corpus
-from repro.core.types import LDAHyperParams
-from repro.train.session import RunConfig, TrainSession
-corpus, _ = synthetic_lda_corpus(0, num_docs=400, num_words=800,
-                                 num_topics=32, avg_doc_len=64)
-hyper = LDAHyperParams(num_topics=32, alpha=0.05, beta=0.01)
-session = TrainSession(corpus, hyper,
-                       RunConfig(algorithm={alg!r}, mesh_shape=(1, 2)))
-state = session.init(jax.random.key(0))
-state = session.step(state)  # warm compile
-jax.block_until_ready(state.n_k)
-t0 = time.perf_counter()
-for _ in range({iters}):
-    state = session.step(state)
-jax.block_until_ready(state.n_k)
-print('US_PER_ITER', (time.perf_counter() - t0) / {iters} * 1e6)
-"""
-
 
 def mesh_sweep(iters: int = 5) -> None:
     """fig3 mesh axis: distributed step time for every mesh-capable
-    backend, 2 simulated CPU devices, (1, 2) data x model mesh."""
-    import repro
-
-    # repro is a namespace package (no __init__.py): locate src via __path__
-    src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=2 "
-        + env.get("XLA_FLAGS", "")
-    ).strip()
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    backend on a (1, 2) data x model mesh of the devices present."""
     from repro.launch.mesh import mesh_backends
 
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        print(f"# fig3 mesh axis skipped: {n_dev} device present, the "
+              f"(1, 2) mesh needs 2")
+        return
+    corpus, _ = synthetic_lda_corpus(0, num_docs=400, num_words=800,
+                                     num_topics=32, avg_doc_len=64)
+    hyper = LDAHyperParams(num_topics=32, alpha=0.05, beta=0.01)
+    platform = jax.devices()[0].platform
     for alg in mesh_backends():
-        # a bad cell (timeout, crash, missing marker) records an error row
-        # and the sweep moves on — one backend never aborts the whole run
-        try:
-            out = subprocess.run(
-                [sys.executable, "-c",
-                 _MESH_CHILD.format(alg=alg, iters=iters)],
-                env=env, capture_output=True, text=True, timeout=1800,
-            )
-        except subprocess.TimeoutExpired:
-            row(f"fig3_mesh2dev_time_per_iter_{alg}", float("nan"),
-                "error=timeout")
-            continue
-        us = next(
-            (float(line.split()[1]) for line in out.stdout.splitlines()
-             if line.startswith("US_PER_ITER")),
-            None,
-        )
-        if out.returncode != 0 or us is None:
-            err = out.stderr.strip().splitlines()
-            row(f"fig3_mesh2dev_time_per_iter_{alg}", float("nan"),
-                "error=" + err[-1][:80] if err else "error")
-            continue
-        row(f"fig3_mesh2dev_time_per_iter_{alg}", us)
+        session = TrainSession(corpus, hyper,
+                               RunConfig(algorithm=alg, mesh_shape=(1, 2)))
+        state = session.init(jax.random.key(0))
+        state = session.step(state)  # warm compile
+        jax.block_until_ready(state.n_k)
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            state = session.step(state)
+        jax.block_until_ready(state.n_k)
+        row(f"fig3_mesh2dev_time_per_iter_{alg}",
+            (time.perf_counter() - t0) / iters * 1e6, f"platform={platform}")
 
 
 def main(iters: int = 10):

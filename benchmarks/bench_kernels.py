@@ -45,6 +45,7 @@ def main() -> None:
         autotune_sparse,
     )
     from repro.kernels.ops import (
+        default_interpret,
         zen_fused_infer_sample,
         zen_infer_sample,
         zen_sample,
@@ -80,7 +81,7 @@ def main() -> None:
             bt=bt, bk=bk, bs=bs,
             t=t, k=k, w=w, d=d, j=j,
             backend=jax.default_backend(),
-            interpret=jax.default_backend() == "cpu",
+            interpret=default_interpret(),
         ))
         row(f"kernels/{kernel}/{label}", us, f"tok/s={tok / us * 1e6:.0f}")
 

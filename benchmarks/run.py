@@ -39,6 +39,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.out_dir:
         os.environ["BENCH_OUT_DIR"] = args.out_dir
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sections = {
         "fig3": lambda: __import__("benchmarks.bench_algorithms",
                                    fromlist=["main"]).main(),

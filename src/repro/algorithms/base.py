@@ -97,7 +97,7 @@ class SamplerKnobs:
     num_mh: int = 8  # LightLDA cycle-MH steps
     token_chunk: int = 0  # bound peak memory by chunking tokens (0 = off)
     bt: int = 256  # Pallas token-tile (zen_pallas + kernel suite v2)
-    bk: int = 512  # Pallas topic-tile (zen_pallas + kernel suite v2)
+    bk: int = 512  # Pallas topic-tile (gather kernels: up to 1024-multiple)
     bs: int = 128  # sparse-row lane-alignment tile (kernel (c))
     kernels: str = "auto"  # kernel dispatch policy: auto | on | off
 
@@ -139,7 +139,9 @@ def kernel_dispatch(mode: str) -> bool:
             f"kernel mode {mode!r}: expected one of {VALID_KERNEL_MODES}"
         )
     if mode == "auto":
-        return jax.default_backend() == "tpu"
+        from repro.kernels.ops import default_interpret
+
+        return not default_interpret()
     return mode == "on"
 
 
@@ -338,18 +340,20 @@ def chunked_token_map(chunk_fn, key, arrays, token_chunk: int) -> jax.Array:
     """Apply ``chunk_fn((arr0, arr1, ..., subkey)) -> (chunk,)`` over token
     chunks (bounds peak memory; 0/oversized chunk = one whole-sweep call).
 
-    Every ``(E,)`` array in ``arrays`` is reshaped to ``(n, token_chunk)``;
-    E must divide evenly."""
+    Every ``(E,)`` array in ``arrays`` is zero-padded to a whole number of
+    chunks and reshaped to ``(n, token_chunk)``; the padded tail (id-0
+    tokens) is sliced off the result."""
     e = arrays[0].shape[0]
     if not token_chunk or token_chunk >= e:
         return chunk_fn(tuple(arrays) + (key,))
-    assert e % token_chunk == 0, (e, token_chunk)
-    n = e // token_chunk
+    n = -(-e // token_chunk)
+    pad = n * token_chunk - e
     keys = jax.random.split(key, n)
     out = jax.lax.map(
-        chunk_fn, tuple(a.reshape(n, -1) for a in arrays) + (keys,)
+        chunk_fn,
+        tuple(jnp.pad(a, (0, pad)).reshape(n, -1) for a in arrays) + (keys,),
     )
-    return out.reshape(e)
+    return out.reshape(-1)[:e]
 
 
 def auto_pad(n: jax.Array, multiple: int = 8) -> int:

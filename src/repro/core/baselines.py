@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.core.alias import AliasTable, build_alias, sample_alias
 from repro.core.decompositions import precompute_zen_terms
 from repro.core.types import CGSState, Corpus, LDAHyperParams
+from repro.kernels.ref import sparse_row_sample_ref
 from repro.core.zen_sparse import SparseRows, lookup_rows, sparsify_rows
 
 
@@ -108,16 +109,8 @@ def sparselda_cell(
         z_r = sparse_row_sample(r_vals, kd_idx, r_target, bt=bt, bs=bs)
         z_q = sparse_row_sample(q_vals, wk_idx, q_target, bt=bt, bs=bs)
     else:
-        r_cdf = jnp.cumsum(r_vals, axis=-1)
-        r_pos = jnp.minimum(
-            jnp.sum(r_cdf < r_target[:, None], axis=-1), r_vals.shape[-1] - 1
-        )
-        z_r = jnp.take_along_axis(kd_idx, r_pos[:, None], axis=-1)[:, 0]
-        q_cdf = jnp.cumsum(q_vals, axis=-1)
-        q_pos = jnp.minimum(
-            jnp.sum(q_cdf < q_target[:, None], axis=-1), q_vals.shape[-1] - 1
-        )
-        z_q = jnp.take_along_axis(wk_idx, q_pos[:, None], axis=-1)[:, 0]
+        z_r = sparse_row_sample_ref(r_vals, kd_idx, r_target)
+        z_q = sparse_row_sample_ref(q_vals, wk_idx, q_target)
 
     z_new = jnp.where(
         u < s_mass, z_s, jnp.where(u < s_mass + r_mass, z_r, z_q)

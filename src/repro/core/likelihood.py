@@ -51,12 +51,15 @@ def predictive_llh(
     e = corpus.word.shape[0]
     if token_chunk is None or token_chunk >= e:
         return jnp.sum(chunk((corpus.word, corpus.doc)))
-    assert e % token_chunk == 0
-    n_chunks = e // token_chunk
+    n_chunks = -(-e // token_chunk)
+    pad = n_chunks * token_chunk - e
     vals = jax.lax.map(
         chunk,
-        (corpus.word.reshape(n_chunks, -1), corpus.doc.reshape(n_chunks, -1)),
+        tuple(jnp.pad(a, (0, pad)).reshape(n_chunks, -1)
+              for a in (corpus.word, corpus.doc)),
     )
+    if pad:  # the padded tail is id-0 tokens: drop their terms
+        vals = vals.reshape(-1)[:e]
     return jnp.sum(vals)
 
 

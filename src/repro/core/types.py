@@ -96,3 +96,11 @@ class CGSState:
         np.testing.assert_array_equal(n_wk.sum(axis=0), n_k)
         np.testing.assert_array_equal(n_kd.sum(axis=0), n_k)
         assert (n_wk >= 0).all() and (n_kd >= 0).all() and (n_k >= 0).all()
+
+
+# a pytree, so a whole training step can be one jitted program
+jax.tree_util.register_dataclass(
+    CGSState,
+    data_fields=[f.name for f in dataclasses.fields(CGSState)],
+    meta_fields=[],
+)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.core.alias import AliasTable, build_alias, sample_alias
 from repro.core.decompositions import ZenTerms, precompute_zen_terms
 from repro.core.types import CGSState, Corpus, LDAHyperParams
+from repro.kernels.ref import sparse_row_sample_ref
 
 
 class SparseRows(NamedTuple):
@@ -168,9 +169,8 @@ def zen_sample_tokens(
     """Sample new topics for T tokens — the faithful two-level ZenLDA draw.
 
     ``use_kernel`` routes the term-3 dSparse inversion through the
-    padded-sparse Pallas kernel (``kernels.sparse_row``). The kernel's op
-    sequence (cumsum, lower-bound count, clamp, topic select) is exactly
-    this function's XLA term-3 sequence, so dispatch is bit-identical."""
+    padded-sparse Pallas kernel (``kernels.sparse_row``); the XLA path is
+    the kernel's bit-exact oracle, so dispatch is bit-identical."""
 
     def draw(key):
         k_u, k_g1, k_g2, k_w1, k_w2, k_d = jax.random.split(key, 6)
@@ -208,10 +208,7 @@ def zen_sample_tokens(
 
             z_d = sparse_row_sample(d_vals, d_topics, target, bt=bt, bs=bs)
         else:
-            cdf = jnp.cumsum(d_vals, axis=-1)
-            pos = jnp.sum(cdf < target[:, None], axis=-1)
-            pos = jnp.minimum(pos, d_vals.shape[-1] - 1)
-            z_d = jnp.take_along_axis(d_topics, pos[:, None], axis=-1)[:, 0]
+            z_d = sparse_row_sample_ref(d_vals, d_topics, target)
 
         branch = jnp.where(u < m1, 0, jnp.where(u < m1 + m2, 1, 2))
         z = jnp.where(branch == 0, z_g, jnp.where(branch == 1, z_w, z_d))

@@ -4,22 +4,20 @@ The first-generation sampler (``zen_sampler.py``) consumes *gathered*
 ``(T, K)`` word/doc count rows: the backend materializes ``n_wk[word]`` and
 ``n_kd[doc]`` in HBM before the kernel ever runs — at webchunk scale that is
 two full token-by-topic matrices of traffic per sweep that exist only to be
-streamed once. This kernel removes the materialization: the per-token
-word/doc *row indices* ride in as scalar-prefetch operands
-(``pltpu.PrefetchScalarGridSpec``), and each grid step's BlockSpec
-``index_map`` uses them to pull the token's ``(1, bk)`` count-row tile
-straight out of the resident ``N_w|k`` / ``N_k|d`` matrices — the gather
-happens in the DMA engine, tile by tile, never as an HBM intermediate
-(CuLDA_CGS's fused gather+sample+update, rendered for the TPU memory
-system; see DESIGN.md §2.3).
+streamed once. This kernel removes the materialization: the resident
+``N_w|k`` / ``N_k|d`` matrices stay in HBM (``memory_space=pl.ANY``), the
+per-token word/doc row ids arrive as SMEM blocks, and each grid step DMAs
+the tile's ``bt`` row slices of width ``bk`` into two ``(bt, bk)`` VMEM
+tiles (``tiles.gather_rows``; ``bk`` is a multiple of 1024 there) — the
+gather happens in the DMA engine, never as an HBM intermediate (CuLDA_CGS's fused
+gather+sample+update, rendered for the TPU memory system; see DESIGN.md
+§2.3).
 
-Grid = (T/bt, bt, K/bk): the middle dimension walks tokens within a token
-tile (one token per step, so the index map can address a single matrix
-row), the innermost walks K tiles with the same running (max, argmax)
-carry as the v1 kernel — now held in a ``(1, 1)`` scalar scratch per
-token. Math, noise coordinates (global token id, topic id), and tie-break
-order are identical to ``_zen_sampler_kernel`` term for term, so the
-fused path is **bit-identical** to the v1 gather-then-sample path (and to
+Grid = (T/bt, K/bk), K innermost, exactly the v1 grid: once a step's rows
+are gathered it runs the v1 kernel body unchanged on the VMEM tiles, so
+math, noise coordinates (global token id, topic id) and tie-break order
+are the v1 kernel's by construction and the fused path is
+**bit-identical** to the v1 gather-then-sample path (and to
 ``ref.zen_fused_sample_ref``) — dispatch choice can never change a run.
 
 Two variants, mirroring v1:
@@ -39,67 +37,90 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.zen_sampler import gumbel_noise
-from repro.utils.compat import pallas_tpu_compiler_params
+from repro.kernels.tiles import (
+    GATHER_LANES,
+    LANES,
+    gather_rows,
+    id_block,
+    row_view,
+)
+from repro.kernels.zen_sampler import _zen_infer_kernel, _zen_sampler_kernel
 
 
-def _fused_sample_kernel(
-    # scalar prefetch
-    seed_ref,  # (1,) int32
-    wids_ref,  # (T,) int32 — per-token word row in N_wk
-    dids_ref,  # (T,) int32 — per-token doc row in N_kd
-    # inputs
-    nwk_ref,  # (1, bk) int32 — word row tile, DMA'd via wids[token]
-    nkd_ref,  # (1, bk) int32 — doc row tile, DMA'd via dids[token]
-    zold_ref,  # (bt, 1) int32 — previous assignment (¬dw exclusion)
-    alpha_ref,  # (1, bk) f32 — alpha_k
-    nk_ref,  # (1, bk) f32 — N_k
-    # output
-    out_ref,  # (bt, 1) int32 — sampled topic
-    # scratch
-    m_ref,  # (1, 1) f32 — running max of log p + g for this token
-    a_ref,  # (1, 1) i32 — running argmax
-    *,
-    beta: float,
-    w_beta: float,
-    bt: int,
-    bk: int,
-):
-    i = pl.program_id(0)
-    t = pl.program_id(1)
-    j = pl.program_id(2)
+def _gathered(body, n_lead: int, ids_per_block: int, kp: int):
+    """Wrap a v1 kernel body: refs are ``lead..., wids, dids, nwk_view,
+    nkd_view, rest..., out, bufs (2), tiles (2), sems, m_ref, a_ref``.
+    Gather this step's word and doc row tiles, then run ``body(lead...,
+    nw_tile, nd_tile, rest..., out, m_ref, a_ref)``."""
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[0, 0] = -jnp.inf
-        a_ref[0, 0] = 0
+    def kernel(*refs, bt: int, bk: int, **params):
+        lead = refs[:n_lead]
+        wids, dids, nwk_view, nkd_view = refs[n_lead:n_lead + 4]
+        rest = refs[n_lead + 4:-7]
+        nw_buf, nd_buf, nw_tile, nd_tile, sems, m_ref, a_ref = refs[-7:]
+        base = pl.program_id(0) % (ids_per_block // bt) * bt
+        gather_rows(
+            ((wids, nwk_view, nw_buf, nw_tile, sems.at[0]),
+             (dids, nkd_view, nd_buf, nd_tile, sems.at[1])),
+            base, kp, pl.program_id(1) * bk,
+        )
+        body(*lead, nw_tile, nd_tile, *rest, m_ref, a_ref, bt=bt, bk=bk,
+             **params)
 
-    tok = i * bt + t  # global token index — v1's noise row coordinate
-    cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    return kernel
 
-    # exact ¬dw: subtract the token's own previous assignment
-    self_hit = (cols == zold_ref[t, 0]).astype(jnp.float32)
-    nw = nwk_ref[...].astype(jnp.float32) - self_hit
-    nd = nkd_ref[...].astype(jnp.float32) - self_hit
-    nk = nk_ref[...] - self_hit
-    alpha_k = alpha_ref[...]
 
-    # three-term ZenLDA decomposition, fused (paper Alg. 5 FMAs)
-    p = (alpha_k * beta + nw * alpha_k + nd * (nw + beta)) / (nk + w_beta)
+def _fused_call(body, n_lead, lead, word, doc, n_wk, n_kd, rest,
+                *, beta, w_beta, bt, bk, interpret):
+    """One pallas_call of a gathered v1 body. ``lead`` are scalar-prefetch
+    operands, ``rest`` the (bt, 1) per-token then (1, bk) per-topic
+    operands of the v1 body, in its order. ``word``/``doc`` are padded to
+    whole SMEM id blocks; ``rest`` holds ``t`` tokens."""
+    t, k = rest[0].shape[0], n_wk.shape[1]
+    ids_per_block = id_block(bt)
+    assert t % bt == 0 and k % bk == 0 and bk % GATHER_LANES == 0, \
+        (t, k, bt, bk)
+    assert word.shape[0] % ids_per_block == 0, (word.shape, ids_per_block)
+    assert n_kd.shape[1] == k, (n_wk.shape, n_kd.shape)
+    per_tile = ids_per_block // bt
 
-    g = gumbel_noise(seed_ref[0], tok, cols)
-    score = jnp.log(jnp.maximum(p, 1e-30)) + g
+    def spec(x):
+        if x.shape[1] == 1:  # per-token column
+            return pl.BlockSpec((bt, 1), lambda i, j, *_: (i, 0))
+        return pl.BlockSpec((1, bk), lambda i, j, *_: (0, j))
 
-    tile_max = jnp.max(score)
-    tile_arg = jnp.argmax(score[0]).astype(jnp.int32) + j * bk
-
-    better = tile_max > m_ref[0, 0]
-    a_ref[0, 0] = jnp.where(better, tile_arg, a_ref[0, 0])
-    m_ref[0, 0] = jnp.where(better, tile_max, m_ref[0, 0])
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _done():
-        out_ref[t, 0] = a_ref[0, 0]
+    ids = pl.BlockSpec((ids_per_block,), lambda i, j, *_: (i // per_tile,),
+                       memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    kernel = functools.partial(
+        _gathered(body, n_lead, ids_per_block, k),
+        beta=beta, w_beta=w_beta, bt=bt, bk=bk,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_lead,
+            grid=(t // bt, k // bk),
+            in_specs=[ids, ids, hbm, hbm] + [spec(x) for x in rest],
+            out_specs=pl.BlockSpec((bt, 1), lambda i, j, *_: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bt * bk // LANES, LANES), jnp.int32),
+                pltpu.VMEM((bt * bk // LANES, LANES), jnp.int32),
+                pltpu.VMEM((bt, bk), jnp.int32),
+                pltpu.VMEM((bt, bk), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((bt, 1), jnp.float32),
+                pltpu.VMEM((bt, 1), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((t, 1), jnp.int32),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+    )(*lead, word.astype(jnp.int32), doc.astype(jnp.int32),
+      row_view(n_wk), row_view(n_kd), *rest)
+    return out[:, 0]
 
 
 def zen_fused_sample_pallas(
@@ -118,107 +139,16 @@ def zen_fused_sample_pallas(
     bk: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Sample one topic per token, gathering count rows in-register.
-    T % bt == 0 and K % bk == 0 required (``ops.zen_fused_sample`` pads)."""
-    t, k = word.shape[0], n_wk.shape[1]
-    assert t % bt == 0 and k % bk == 0, (t, k, bt, bk)
-    assert n_kd.shape[1] == k, (n_wk.shape, n_kd.shape)
-    grid = (t // bt, bt, k // bk)
-    kernel = functools.partial(
-        _fused_sample_kernel, beta=beta, w_beta=w_beta, bt=bt, bk=bk
+    """Sample one topic per token, gathering count rows by DMA. T % bt ==
+    0, K % bk == 0, bk % 1024 == 0 and word/doc padded to whole id blocks
+    required (``ops.zen_fused_sample`` pads)."""
+    return _fused_call(
+        _zen_sampler_kernel, 1, (jnp.asarray([seed], jnp.int32),),
+        word, doc, n_wk, n_kd,
+        (z_old[:, None], alpha_k[None, :].astype(jnp.float32),
+         n_k[None, :].astype(jnp.float32)),
+        beta=beta, w_beta=w_beta, bt=bt, bk=bk, interpret=interpret,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bk), lambda i, t, j, s, w, d: (w[i * bt + t], j)),
-                pl.BlockSpec((1, bk), lambda i, t, j, s, w, d: (d[i * bt + t], j)),
-                pl.BlockSpec((bt, 1), lambda i, t, j, s, w, d: (i, 0)),
-                pl.BlockSpec((1, bk), lambda i, t, j, s, w, d: (0, j)),
-                pl.BlockSpec((1, bk), lambda i, t, j, s, w, d: (0, j)),
-            ],
-            out_specs=pl.BlockSpec((bt, 1), lambda i, t, j, s, w, d: (i, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((1, 1), jnp.int32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((t, 1), jnp.int32),
-        interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
-    )(
-        jnp.asarray([seed], jnp.int32),
-        word.astype(jnp.int32),
-        doc.astype(jnp.int32),
-        n_wk,
-        n_kd,
-        z_old[:, None],
-        alpha_k[None, :].astype(jnp.float32),
-        n_k[None, :].astype(jnp.float32),
-    )
-    return out[:, 0]
-
-
-def _fused_infer_kernel(
-    # scalar prefetch
-    wids_ref,  # (T,) int32 — per-token word row in the frozen N_wk
-    dids_ref,  # (T,) int32 — per-token slot row in the slot-batch N_kd
-    # inputs
-    nwk_ref,  # (1, bk) int32 — frozen word row tile
-    nkd_ref,  # (1, bk) int32 — slot doc row tile
-    zold_ref,  # (bt, 1) int32 — previous assignment (doc-side ¬t)
-    seed_ref,  # (bt, 1) int32 — per-token counter-based seeds
-    alpha_ref,  # (1, bk) f32 — alpha_k
-    nk_ref,  # (1, bk) f32 — frozen N_k
-    # output
-    out_ref,  # (bt, 1) int32
-    # scratch
-    m_ref,  # (1, 1) f32
-    a_ref,  # (1, 1) i32
-    *,
-    beta: float,
-    w_beta: float,
-    bt: int,
-    bk: int,
-):
-    """Frozen-model serving variant: doc-side-only exclusion, per-token
-    seeds with (seed, 0, topic) noise coordinates — the exact contract of
-    ``_zen_infer_kernel``, minus its gathered-row inputs."""
-    t = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[0, 0] = -jnp.inf
-        a_ref[0, 0] = 0
-
-    cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-
-    self_hit = (cols == zold_ref[t, 0]).astype(jnp.float32)
-    nw = nwk_ref[...].astype(jnp.float32)
-    nd = nkd_ref[...].astype(jnp.float32) - self_hit
-    alpha_k = alpha_ref[...]
-
-    # frozen-phi conditional: (N_k|d^(¬t) + alpha_k)(N_w|k + beta)/(N_k + Wβ)
-    p = (nd + alpha_k) * (nw + beta) / (nk_ref[...] + w_beta)
-
-    g = gumbel_noise(seed_ref[t, 0], jnp.uint32(0), cols)
-    score = jnp.log(jnp.maximum(p, 1e-30)) + g
-
-    tile_max = jnp.max(score)
-    tile_arg = jnp.argmax(score[0]).astype(jnp.int32) + j * bk
-
-    better = tile_max > m_ref[0, 0]
-    a_ref[0, 0] = jnp.where(better, tile_arg, a_ref[0, 0])
-    m_ref[0, 0] = jnp.where(better, tile_max, m_ref[0, 0])
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _done():
-        out_ref[t, 0] = a_ref[0, 0]
 
 
 def zen_fused_infer_sample_pallas(
@@ -237,48 +167,13 @@ def zen_fused_infer_sample_pallas(
     bk: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Frozen-model Gumbel-max sample with in-register row gather.
-    T % bt == 0 and K % bk == 0 required (``ops.zen_fused_infer_sample``
-    pads)."""
-    t, k = word.shape[0], n_wk.shape[1]
-    assert t % bt == 0 and k % bk == 0, (t, k, bt, bk)
-    assert n_kd.shape[1] == k, (n_wk.shape, n_kd.shape)
-    grid = (t // bt, bt, k // bk)
-    kernel = functools.partial(
-        _fused_infer_kernel, beta=beta, w_beta=w_beta, bt=bt, bk=bk
+    """Frozen-model Gumbel-max sample with the row gather done by DMA.
+    Same shape contract as ``zen_fused_sample_pallas``
+    (``ops.zen_fused_infer_sample`` pads)."""
+    return _fused_call(
+        _zen_infer_kernel, 0, (), word, slot, n_wk, n_kd,
+        (z_old[:, None], seeds[:, None].astype(jnp.int32),
+         alpha_k[None, :].astype(jnp.float32),
+         n_k[None, :].astype(jnp.float32)),
+        beta=beta, w_beta=w_beta, bt=bt, bk=bk, interpret=interpret,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bk), lambda i, t, j, w, d: (w[i * bt + t], j)),
-                pl.BlockSpec((1, bk), lambda i, t, j, w, d: (d[i * bt + t], j)),
-                pl.BlockSpec((bt, 1), lambda i, t, j, w, d: (i, 0)),
-                pl.BlockSpec((bt, 1), lambda i, t, j, w, d: (i, 0)),
-                pl.BlockSpec((1, bk), lambda i, t, j, w, d: (0, j)),
-                pl.BlockSpec((1, bk), lambda i, t, j, w, d: (0, j)),
-            ],
-            out_specs=pl.BlockSpec((bt, 1), lambda i, t, j, w, d: (i, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((1, 1), jnp.int32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((t, 1), jnp.int32),
-        interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
-    )(
-        word.astype(jnp.int32),
-        slot.astype(jnp.int32),
-        n_wk,
-        n_kd,
-        z_old[:, None],
-        seeds[:, None],
-        alpha_k[None, :].astype(jnp.float32),
-        n_k[None, :].astype(jnp.float32),
-    )
-    return out[:, 0]

@@ -1,7 +1,10 @@
 """Jitted public wrappers for the Pallas kernels (padding, dtype glue).
 
-``interpret`` defaults to True on CPU (validation) and False on TPU
-(production); callers can force either.
+``interpret=None`` resolves through ``default_interpret`` — the one place
+that decides: compiled Mosaic kernels when JAX's devices are TPUs,
+interpret mode (bit-exact validation) otherwise. Callers can force
+either; compiling for a described TPU from a CPU host must pass
+``interpret=False``.
 """
 from __future__ import annotations
 
@@ -16,6 +19,7 @@ from repro.kernels.fused_gather import (
     zen_fused_sample_pallas,
 )
 from repro.kernels.sparse_row import sparse_row_sample_pallas
+from repro.kernels.tiles import gather_bk, id_block
 from repro.kernels.topic_histogram import topic_histogram_pallas
 from repro.kernels.zen_sampler import (
     zen_infer_sample_pallas,
@@ -27,8 +31,17 @@ from repro.kernels.zen_sampler import (
 _SPARSE_ROW_BUDGET = 1 << 18
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+def default_interpret() -> bool:
+    """Whether kernels run in interpret mode when the caller leaves it
+    open: True unless JAX's default backend is a TPU."""
+    return jax.default_backend() != "tpu"
+
+
+def _gather_tiles(t: int, bt: int, bk: int) -> tuple[int, int, int]:
+    """(bt, bk, id block) of the gather kernels for ``t`` tokens: bt a
+    multiple of 8 no larger than needed, bk whole (8, 128) row tiles."""
+    bt_eff = min(bt, -(-max(t, 8) // 8) * 8)
+    return bt_eff, gather_bk(bk), id_block(bt_eff)
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int, value=0) -> jax.Array:
@@ -65,7 +78,7 @@ def zen_sample(
     so padded topics can never win the argmax.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = default_interpret()
     t, k = nwk_rows.shape
     bt_eff = min(bt, max(8, t))
     nwk_p = _pad_to(_pad_to(nwk_rows, 0, bt_eff), 1, bk)
@@ -107,7 +120,7 @@ def zen_infer_sample(
     topic can never win the argmax.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = default_interpret()
     t, k = nwk_rows.shape
     bt_eff = min(bt, max(8, t))
     nwk_p = _pad_to(_pad_to(nwk_rows, 0, bt_eff), 1, bk)
@@ -144,23 +157,24 @@ def zen_fused_sample(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused gather+sample (see fused_gather.py): ``zen_sample`` without
-    the ``(T, K)`` gathered-row HBM intermediate — the per-token word/doc
-    ids are scalar-prefetched and the count rows are tiled straight out of
-    the resident matrices. Bit-identical to
-    ``zen_sample(n_wk[word], n_kd[doc], ...)`` for real tokens.
+    the ``(T, K)`` gathered-row HBM intermediate — the count rows are
+    DMA'd per token straight out of the resident matrices. Bit-identical
+    to ``zen_sample(n_wk[word], n_kd[doc], ...)`` for real tokens, at any
+    tiling.
 
-    Pads T to bt (row-0 tokens, sliced off) and K to bk on the resident
-    matrices; K padding gets alpha_k = 0 / counts 0 / n_k = 1e9 so p == 0
-    there and a padded topic can never win the argmax.
+    Pads T to bt (row-0 tokens, sliced off) and K to the gather tile
+    (``bk`` rounded up to a multiple of 1024) on the resident matrices;
+    K padding gets alpha_k = 0 / counts 0 / n_k = 1e9 so p == 0 there and
+    a padded topic can never win the argmax.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = default_interpret()
     t = word.shape[0]
-    bt_eff = min(bt, max(8, t))
+    bt_eff, bk, ids = _gather_tiles(t, bt, bk)
     nwk_p = _pad_to(n_wk.astype(jnp.int32), 1, bk)
     nkd_p = _pad_to(n_kd.astype(jnp.int32), 1, bk)
-    w_p = _pad_to(word, 0, bt_eff)
-    d_p = _pad_to(doc, 0, bt_eff)
+    w_p = _pad_to(word, 0, ids)
+    d_p = _pad_to(doc, 0, ids)
     z_p = _pad_to(z_old, 0, bt_eff)
     a_p = _pad_to(alpha_k.astype(jnp.float32), 0, bk, value=0.0)
     nk_p = _pad_to(n_k.astype(jnp.float32), 0, bk, value=1e9)
@@ -195,18 +209,18 @@ def zen_fused_infer_sample(
     without the gathered-row intermediates. Bit-identical to
     ``zen_infer_sample(n_wk[word], n_kd[slot], ...)`` for real tokens.
 
-    Padding contract matches ``zen_infer_sample``: T pads to bt with
-    row-0/seed-0 tokens (sliced off), K pads to bk with alpha_k = 0 /
-    counts 0 / n_k = 1e9.
+    Padding contract matches ``zen_fused_sample``: T pads to bt with
+    row-0/seed-0 tokens (sliced off), K pads to the gather tile with
+    alpha_k = 0 / counts 0 / n_k = 1e9.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = default_interpret()
     t = word.shape[0]
-    bt_eff = min(bt, max(8, t))
+    bt_eff, bk, ids = _gather_tiles(t, bt, bk)
     nwk_p = _pad_to(n_wk.astype(jnp.int32), 1, bk)
     nkd_p = _pad_to(n_kd.astype(jnp.int32), 1, bk)
-    w_p = _pad_to(word, 0, bt_eff)
-    s_p = _pad_to(slot, 0, bt_eff)
+    w_p = _pad_to(word, 0, ids)
+    s_p = _pad_to(slot, 0, ids)
     z_p = _pad_to(z_old, 0, bt_eff)
     seeds_p = _pad_to(seeds, 0, bt_eff)
     a_p = _pad_to(alpha_k.astype(jnp.float32), 0, bk, value=0.0)
@@ -235,19 +249,20 @@ def cdf_row_search(
     """Fused gather + CDF lower-bound search (see cdf_search.py): the
     index of ``targets[t]`` in ``cumsum(counts[rows[t]] * term)``, clamped
     to K-1, without materializing the float CDF matrix or the gathered
-    rows. Bit-identical to ``ref.cdf_row_search_ref`` at the same bk.
+    rows. Bit-identical to ``ref.cdf_row_search_ref`` at the same bk (both
+    walk K in tiles of ``bk`` rounded up to a multiple of 1024).
 
-    Pads T to bt (row-0 tokens, sliced off) and K to bk with term = 0, so
-    padded topics add no mass; the in-kernel clamp keeps any counts past
-    K-1 from escaping.
+    Pads T to bt (row-0 tokens, sliced off) and K to the gather tile with
+    term = 0, so padded topics add no mass; the in-kernel clamp keeps any
+    counts past K-1 from escaping.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = default_interpret()
     t = rows.shape[0]
     k = counts.shape[1]
-    bt_eff = min(bt, max(8, t))
+    bt_eff, bk, ids = _gather_tiles(t, bt, bk)
     counts_p = _pad_to(counts.astype(jnp.int32), 1, bk)
-    rows_p = _pad_to(rows, 0, bt_eff)
+    rows_p = _pad_to(rows, 0, ids)
     term_p = _pad_to(term.astype(jnp.float32), 0, bk, value=0.0)
     tgt_p = _pad_to(targets.astype(jnp.float32), 0, bt_eff)
     out = cdf_row_search_pallas(
@@ -281,7 +296,7 @@ def sparse_row_sample(
     row budget.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = default_interpret()
     t, j = vals.shape
     vals_p = _pad_to(vals.astype(jnp.float32), 1, bs)
     topics_p = _pad_to(topics.astype(jnp.int32), 1, bs)
@@ -320,7 +335,7 @@ def topic_histogram(
     Padding tokens get inc=0 (inert) and row = last row (stays sorted).
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = default_interpret()
     t = rows_sorted.shape[0]
     bt_eff = min(bt, max(8, t))
     last_row = rows_sorted[-1]

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.tiles import gather_bk, prefix_sum
 from repro.kernels.zen_sampler import gumbel_noise
 
 
@@ -129,12 +130,15 @@ def cdf_row_search_ref(
     bk: int = 512,
 ) -> jax.Array:
     """Tile-accurate oracle of ``ops.cdf_row_search``: same K-tile walk,
-    same carry adds, same op order — so float round-off matches the kernel
-    bit for bit at the same ``bk``. (A whole-row ``searchsorted`` would be
-    the *mathematical* spec but could disagree on round-off at tile
-    boundaries; the tiled walk IS the kernel's contract.)"""
+    same ``prefix_sum`` scan, same carry adds — so float round-off matches
+    the kernel bit for bit at the same ``bk`` (rounded up to the kernel's
+    1024-lane gather tile, as the kernel does). (A whole-row
+    ``searchsorted`` would be the *mathematical* spec but could disagree
+    on round-off at tile boundaries; the tiled walk IS the kernel's
+    contract.)"""
     t = rows.shape[0]
     k = counts.shape[1]
+    bk = gather_bk(bk)
     pad = (-k) % bk
     vals = counts[rows].astype(jnp.float32) * term.astype(jnp.float32)[None, :]
     if pad:
@@ -143,10 +147,10 @@ def cdf_row_search_ref(
     acc = jnp.zeros((t,), jnp.float32)
     cnt = jnp.zeros((t,), jnp.int32)
     for j in range(0, k + pad, bk):
-        tile = vals[:, j:j + bk]
-        cdf = acc[:, None] + jnp.cumsum(tile, axis=1)
+        local = prefix_sum(vals[:, j:j + bk])
+        cdf = acc[:, None] + local
         cnt = cnt + jnp.sum((cdf < tgt).astype(jnp.int32), axis=1)
-        acc = acc + jnp.sum(tile, axis=1)
+        acc = acc + local[:, -1]
     return jnp.minimum(cnt, k - 1)
 
 
@@ -155,13 +159,14 @@ def sparse_row_sample_ref(
     topics: jax.Array,
     targets: jax.Array,
 ) -> jax.Array:
-    """Bit-exact oracle of ``ops.sparse_row_sample``. Lane padding in the
-    wrapper is provably inert (weight-0 lanes leave every real prefix sum
-    bitwise unchanged and the clamp stops at the last real lane), so the
-    oracle needs no padding replication."""
+    """Bit-exact oracle of ``ops.sparse_row_sample``, and the XLA path of
+    every padded-sparse backend's row inversion. Lane padding in the
+    wrapper is provably inert (lanes appended on the right leave every
+    real ``prefix_sum`` lane bitwise unchanged and the clamp stops at the
+    last real lane), so the oracle needs no padding replication."""
     j = vals.shape[1]
     vals_f = vals.astype(jnp.float32)
-    cdf = jnp.cumsum(vals_f, axis=1)
+    cdf = prefix_sum(vals_f)
     tgt = targets.astype(jnp.float32)[:, None]
     cnt = jnp.sum((cdf < tgt).astype(jnp.int32), axis=1)
     pos = jnp.minimum(cnt, j - 1)

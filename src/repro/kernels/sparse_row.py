@@ -27,8 +27,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.compat import pallas_tpu_compiler_params
+from repro.kernels.tiles import prefix_sum
 
 
 def _sparse_row_kernel(
@@ -40,11 +41,12 @@ def _sparse_row_kernel(
     j_real: int,
 ):
     vals = vals_ref[...]
-    cdf = jnp.cumsum(vals, axis=1)
-    cnt = jnp.sum((cdf < tgt_ref[...]).astype(jnp.int32), axis=1)
+    cdf = prefix_sum(vals, roll=pltpu.roll)
+    cnt = jnp.sum((cdf < tgt_ref[...]).astype(jnp.int32), axis=1,
+                  keepdims=True)
     pos = jnp.minimum(cnt, j_real - 1)
     lanes = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-    hit = (lanes == pos[:, None]).astype(jnp.int32)
+    hit = (lanes == pos).astype(jnp.int32)
     out_ref[...] = jnp.sum(topics_ref[...] * hit, axis=1, keepdims=True)
 
 
@@ -76,7 +78,7 @@ def sparse_row_sample_pallas(
         out_specs=pl.BlockSpec((bt, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, 1), jnp.int32),
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
     )(

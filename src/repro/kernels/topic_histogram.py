@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.compat import pallas_tpu_compiler_params
 
 
 def _hist_kernel(
@@ -109,7 +108,7 @@ def topic_histogram_pallas(
         out_specs=pl.BlockSpec((bt, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, k), jnp.int32),
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
     )(rank[:, None], z_old[:, None], z_new[:, None], inc[:, None])

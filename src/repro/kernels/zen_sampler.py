@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.compat import pallas_tpu_compiler_params
 
 # Murmur3-style finalizer constants (avalanche mixing). Plain ints: traced
 # jnp constants would be captured as closure constants, which pallas rejects.
@@ -56,15 +55,21 @@ def _mix(x: jax.Array) -> jax.Array:
 def hash_uniform(seed: jax.Array, row: jax.Array, col: jax.Array) -> jax.Array:
     """Counter-based U(0,1) from integer coordinates. Shared by kernel + ref.
 
-    24-bit mantissa construction keeps the value in (0, 1) exactly the same
-    way on TPU and CPU.
+    The top 23 hash bits m give ``u = (2m + 1) / 2^24``: every value is
+    exact in float32 and lies in [2^-24, 1 - 2^-24], so the Gumbel noise
+    ``-log(-log(u))`` is always finite, the same way on TPU and CPU. (24
+    bits rounded the top value up to 1.0, an infinite Gumbel that let a
+    zero-probability padded topic win the argmax.) The bits go to float
+    through int32: Mosaic has no uint32 -> float32 cast.
     """
     h = _mix(
         seed.astype(jnp.uint32)
         ^ (row.astype(jnp.uint32) * jnp.asarray(_GOLD, jnp.uint32))
         ^ _mix(col.astype(jnp.uint32))
     )
-    return (h >> 8).astype(jnp.float32) * (1.0 / (1 << 24)) + (0.5 / (1 << 24))
+    return (h >> 9).astype(jnp.int32).astype(jnp.float32) * (
+        1.0 / (1 << 23)
+    ) + (0.5 / (1 << 23))
 
 
 def gumbel_noise(seed, row, col):
@@ -201,7 +206,7 @@ def zen_sample_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((t, 1), jnp.int32),
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(
@@ -322,7 +327,7 @@ def zen_infer_sample_pallas(
         ],
         out_shape=jax.ShapeDtypeStruct((t, 1), jnp.int32),
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(

@@ -112,6 +112,10 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.data import synthetic_corpus
     from repro.data.corpus import load_libsvm
     from repro.observe import summarize_latencies

@@ -9,9 +9,10 @@ elastic training checkpoints, exclusion enablement, exact count rebuild,
 padded-row re-resolution, duplicate-topic merging — fire from the
 session's schedule. Every backend with ``supports_shard_map`` runs the
 mesh plan; only backends without a cell sweep (std) fall back to
-single-box. On a real TPU slice the mesh plan runs under
-``jax.distributed``; on CPU hosts pass --host-devices to simulate N
-devices.
+single-box. One process drives every local chip: on a four-chip TPU host
+``--rows 2 --cols 2`` lays the mesh over all four; on CPU hosts pass
+--host-devices to simulate N devices. The persistent compile cache goes
+to ``$JAX_COMPILATION_CACHE_DIR`` or ``.jax_cache/`` in the checkout.
 
     PYTHONPATH=src python -m repro.launch.train \
         --rows 2 --cols 2 --host-devices 4 --iters 50 \
@@ -203,6 +204,9 @@ def main() -> None:
 
     from repro import algorithms
     from repro.train.session import RunConfig, TrainSession
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.list_algorithms:
         for name, backend, aliases in algorithms.describe():

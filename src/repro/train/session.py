@@ -35,6 +35,7 @@ delegating here; new code should construct sessions directly.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 import signal
@@ -326,6 +327,9 @@ class SingleBoxPlan(ExecutionPlan):
         # the schedule's "exclusion_on" firing is a recorded no-op here;
         # on the mesh plan it swaps the compiled step for real
         self._excl = cfg.exclusion()
+        # compiled steps (``compiled_step``); the backend, hyper and
+        # exclusion config are baked in, so changing any of them clears it
+        self._compiled: Dict[Any, Any] = {}
 
     # -- lifecycle ---------------------------------------------------------
     def init(self, rng: jax.Array, init_topics=None) -> CGSState:
@@ -349,21 +353,50 @@ class SingleBoxPlan(ExecutionPlan):
             return init_lib.sparse_doc_init(rng, c, h, cfg.sparse_init_degree)
         raise ValueError(cfg.init)
 
-    def sweep(self, state: CGSState) -> jax.Array:
-        knobs = self._knobs
+    def _sweep_knobs(self, state: CGSState) -> SamplerKnobs:
         if self.backend.needs_row_pads:
             # host-side auto pads from the current counts (0 = auto):
             # single-box re-resolves every sweep, so row growth never
             # truncates here (the mesh plan re-pads on the rebuild cadence)
-            knobs = algorithms.resolve_row_pads(state, knobs)
-        return self.backend.sweep(state, self.corpus, self.hyper, knobs,
-                                  self._aux)
+            return algorithms.resolve_row_pads(state, self._knobs)
+        return self._knobs
+
+    def sweep(self, state: CGSState) -> jax.Array:
+        return self.backend.sweep(state, self.corpus, self.hyper,
+                                  self._sweep_knobs(state), self._aux)
 
     def step(self, state: CGSState) -> CGSState:
-        c, h = self.corpus, self.hyper
+        compiled, args = self.compiled_step(state)
+        return compiled(*args)
+
+    def compiled_step(self, state: CGSState):
+        """The step as one compiled program, and its arguments: ``(exe,
+        args)`` with ``exe(*args)`` the next state. Compiled once per
+        sampler knobs and argument shapes (padded-sparse backends resolve
+        their row widths from ``state`` first); ``exe`` exposes
+        ``memory_analysis()`` and ``as_text()``."""
+        knobs = self._sweep_knobs(state)
+        state = dataclasses.replace(
+            state, iteration=jnp.asarray(state.iteration, jnp.int32)
+        )
+        args = (state, self.corpus.word, self.corpus.doc, self._aux)
+        sig = (knobs, jax.tree.structure(args),
+               tuple((x.shape, x.dtype) for x in jax.tree.leaves(args)))
+        exe = self._compiled.get(sig)
+        if exe is None:
+            if len(self._compiled) >= 8:  # padded-sparse widths drift
+                self._compiled.pop(next(iter(self._compiled)))
+            exe = jax.jit(functools.partial(self._step, knobs)).lower(
+                *args).compile()
+            self._compiled[sig] = exe
+        return exe, args
+
+    def _step(self, knobs, state: CGSState, word, doc, aux) -> CGSState:
+        c = dataclasses.replace(self.corpus, word=word, doc=doc)
+        h = self.hyper
         key = jax.random.fold_in(state.rng, 2**20 + state.iteration)
         mask = active_mask(state, self._excl, key)
-        z_new_all = self.sweep(state)
+        z_new_all = self.backend.sweep(state, c, h, knobs, aux)
         z_new = jnp.where(mask, z_new_all, state.topic)
         d_wk, d_kd, d_k = counts_lib.delta_counts(
             c.word, c.doc, state.topic, z_new, c.num_words, c.num_docs,
@@ -402,6 +435,7 @@ class SingleBoxPlan(ExecutionPlan):
     # -- structural events -------------------------------------------------
     def enable_exclusion(self) -> None:
         self._excl = self.cfg.exclusion()  # idempotent (in-trace warmup)
+        self._compiled.clear()
 
     def rebuild(self, state: CGSState) -> CGSState:
         c, h = self.corpus, self.hyper
@@ -430,12 +464,14 @@ class SingleBoxPlan(ExecutionPlan):
         self.backend = algorithms.get(name)
         self._aux = self.backend.prepare(self.corpus, self.hyper,
                                          self._knobs)
+        self._compiled.clear()
         return True
 
     def set_hyper(self, hyper: LDAHyperParams) -> None:
         self.hyper = hyper
         # aux tables may encode beta/alpha (alias tables, frozen CDFs)
         self._aux = self.backend.prepare(self.corpus, hyper, self._knobs)
+        self._compiled.clear()
 
     def merge(self, state: CGSState, topic_map) -> CGSState:
         tm = jnp.asarray(topic_map, jnp.int32)

@@ -1,57 +1,25 @@
-"""JAX version compatibility shims (the repo targets both the pinned
-container jax and current releases).
+"""Mesh and ``shard_map`` helpers for the installed jax (0.9).
 
-* ``shard_map`` — ``jax.shard_map(..., check_vma=)`` on new jax,
-  ``jax.experimental.shard_map.shard_map(..., check_rep=)`` on old.
-* ``make_mesh`` — newer ``jax.make_mesh`` takes ``axis_types``; older
-  versions don't have the kwarg (or ``jax.sharding.AxisType`` at all).
+* ``make_mesh`` — a device mesh whose axes are all ``AxisType.Auto``: the
+  LDA steps place data with ``NamedSharding``s and let the compiler
+  propagate them.
+* ``shard_map`` — ``jax.shard_map`` with replication checking off by
+  default (the LDA steps mix replicated and sharded outputs on purpose).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """Explicit-axes mesh across jax versions."""
-    shape, axes = tuple(shape), tuple(axes)
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
-
-
-def pallas_tpu_compiler_params(**kwargs):
-    """jax renamed ``pltpu.TPUCompilerParams`` -> ``CompilerParams``."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    return cls(**kwargs)
-
-
-def abstract_mesh(shape, axes):
-    """Device-less mesh for spec math: newer jax takes (sizes, names),
-    older takes one ((name, size), ...) shape tuple."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """Device mesh of ``shape`` over ``axes``, every axis Auto."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """Per-device SPMD mapping; replication checking off by default (the
-    LDA steps mix replicated and sharded outputs on purpose)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
+    """Per-device SPMD mapping of ``f`` over ``mesh``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

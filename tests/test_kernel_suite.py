@@ -14,7 +14,7 @@ walker is proven able to see the thing it asserts absent.
 import dataclasses
 
 import jax
-import jax.core
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -119,9 +119,9 @@ def _collect_avals(jaxpr, out):
 
 
 def _sub_jaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jax.extend.core.ClosedJaxpr):
         return [val.jaxpr]
-    if isinstance(val, jax.core.Jaxpr):
+    if isinstance(val, jax.extend.core.Jaxpr):
         return [val]
     if isinstance(val, (list, tuple)):
         subs = []
@@ -137,7 +137,10 @@ def test_fused_cell_path_never_materializes_token_by_topic(monkeypatch):
     anything else token-by-topic) stay virtual. The legacy path is the
     positive control proving the walker sees such values when they exist."""
     monkeypatch.delenv("REPRO_KERNELS", raising=False)
-    t, k, w, d = 192, 16, 37, 23  # t > w, d and k < all row counts
+    # t > 8w, 8d (a count row padded to 1024 lanes is 8 rows of the
+    # kernel's (rows * K / 128, 128) gather view), t > the 256-token tile,
+    # and k < all row counts
+    t, k, w, d = 320, 16, 37, 23
     be = algorithms.get("zen_pallas")
     hyper = LDAHyperParams(num_topics=k, alpha=0.1, beta=0.05)
     mask = jnp.ones((t,), bool)

@@ -17,13 +17,13 @@ import jax, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.configs import get_config, list_archs
 from repro.launch.specs import params_abstract
-from repro.utils.compat import abstract_mesh
+from jax.sharding import AbstractMesh
 from repro.sharding.partition import param_specs
 
 # the REAL production meshes, as abstract shapes (no 512 devices needed)
 MESHES = [
-    abstract_mesh((16, 16), ('data', 'model')),
-    abstract_mesh((2, 16, 16), ('pod', 'data', 'model')),
+    AbstractMesh((16, 16), ('data', 'model')),
+    AbstractMesh((2, 16, 16), ('pod', 'data', 'model')),
 ]
 
 def axis_size(mesh, entry):
