@@ -135,6 +135,7 @@ def cdf_row_search_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="cdf_search",
     )(
         rows.astype(jnp.int32),
         row_view(counts),

@@ -70,12 +70,13 @@ def _gathered(body, n_lead: int, ids_per_block: int, kp: int):
     return kernel
 
 
-def _fused_call(body, n_lead, lead, word, doc, n_wk, n_kd, rest,
+def _fused_call(name, body, n_lead, lead, word, doc, n_wk, n_kd, rest,
                 *, beta, w_beta, bt, bk, interpret):
-    """One pallas_call of a gathered v1 body. ``lead`` are scalar-prefetch
-    operands, ``rest`` the (bt, 1) per-token then (1, bk) per-topic
-    operands of the v1 body, in its order. ``word``/``doc`` are padded to
-    whole SMEM id blocks; ``rest`` holds ``t`` tokens."""
+    """One pallas_call, named ``name``, of a gathered v1 body. ``lead`` are
+    scalar-prefetch operands, ``rest`` the (bt, 1) per-token then (1, bk)
+    per-topic operands of the v1 body, in its order. ``word``/``doc`` are
+    padded to whole SMEM id blocks; ``rest`` holds ``t`` tokens. The count
+    matrices' row views are taken under the named scope ``zen.relayout``."""
     t, k = rest[0].shape[0], n_wk.shape[1]
     ids_per_block = id_block(bt)
     assert t % bt == 0 and k % bk == 0 and bk % GATHER_LANES == 0, \
@@ -96,6 +97,8 @@ def _fused_call(body, n_lead, lead, word, doc, n_wk, n_kd, rest,
         _gathered(body, n_lead, ids_per_block, k),
         beta=beta, w_beta=w_beta, bt=bt, bk=bk,
     )
+    with jax.named_scope("zen.relayout"):
+        nwk_view, nkd_view = row_view(n_wk), row_view(n_kd)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -118,8 +121,9 @@ def _fused_call(body, n_lead, lead, word, doc, n_wk, n_kd, rest,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(*lead, word.astype(jnp.int32), doc.astype(jnp.int32),
-      row_view(n_wk), row_view(n_kd), *rest)
+        name=name,
+    )(*lead, word.astype(jnp.int32), doc.astype(jnp.int32), nwk_view,
+      nkd_view, *rest)
     return out[:, 0]
 
 
@@ -143,8 +147,8 @@ def zen_fused_sample_pallas(
     0, K % bk == 0, bk % 1024 == 0 and word/doc padded to whole id blocks
     required (``ops.zen_fused_sample`` pads)."""
     return _fused_call(
-        _zen_sampler_kernel, 1, (jnp.asarray([seed], jnp.int32),),
-        word, doc, n_wk, n_kd,
+        "zen_fused_sample", _zen_sampler_kernel, 1,
+        (jnp.asarray([seed], jnp.int32),), word, doc, n_wk, n_kd,
         (z_old[:, None], alpha_k[None, :].astype(jnp.float32),
          n_k[None, :].astype(jnp.float32)),
         beta=beta, w_beta=w_beta, bt=bt, bk=bk, interpret=interpret,
@@ -171,7 +175,8 @@ def zen_fused_infer_sample_pallas(
     Same shape contract as ``zen_fused_sample_pallas``
     (``ops.zen_fused_infer_sample`` pads)."""
     return _fused_call(
-        _zen_infer_kernel, 0, (), word, slot, n_wk, n_kd,
+        "zen_fused_infer_sample", _zen_infer_kernel, 0, (), word, slot,
+        n_wk, n_kd,
         (z_old[:, None], seeds[:, None].astype(jnp.int32),
          alpha_k[None, :].astype(jnp.float32),
          n_k[None, :].astype(jnp.float32)),

@@ -5,6 +5,10 @@ that decides: compiled Mosaic kernels when JAX's devices are TPUs,
 interpret mode (bit-exact validation) otherwise. Callers can force
 either; compiling for a described TPU from a CPU host must pass
 ``interpret=False``.
+
+The fused wrappers pad the resident count matrices to the gather tile and
+take their row views under the named scope ``zen.relayout``, so that
+re-layout's ops are found by name in a trace of the enclosing step.
 """
 from __future__ import annotations
 
@@ -171,8 +175,9 @@ def zen_fused_sample(
         interpret = default_interpret()
     t = word.shape[0]
     bt_eff, bk, ids = _gather_tiles(t, bt, bk)
-    nwk_p = _pad_to(n_wk.astype(jnp.int32), 1, bk)
-    nkd_p = _pad_to(n_kd.astype(jnp.int32), 1, bk)
+    with jax.named_scope("zen.relayout"):
+        nwk_p = _pad_to(n_wk.astype(jnp.int32), 1, bk)
+        nkd_p = _pad_to(n_kd.astype(jnp.int32), 1, bk)
     w_p = _pad_to(word, 0, ids)
     d_p = _pad_to(doc, 0, ids)
     z_p = _pad_to(z_old, 0, bt_eff)
@@ -217,8 +222,9 @@ def zen_fused_infer_sample(
         interpret = default_interpret()
     t = word.shape[0]
     bt_eff, bk, ids = _gather_tiles(t, bt, bk)
-    nwk_p = _pad_to(n_wk.astype(jnp.int32), 1, bk)
-    nkd_p = _pad_to(n_kd.astype(jnp.int32), 1, bk)
+    with jax.named_scope("zen.relayout"):
+        nwk_p = _pad_to(n_wk.astype(jnp.int32), 1, bk)
+        nkd_p = _pad_to(n_kd.astype(jnp.int32), 1, bk)
     w_p = _pad_to(word, 0, ids)
     s_p = _pad_to(slot, 0, ids)
     z_p = _pad_to(z_old, 0, bt_eff)

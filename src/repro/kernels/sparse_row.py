@@ -81,6 +81,7 @@ def sparse_row_sample_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
+        name="sparse_row",
     )(
         vals.astype(jnp.float32),
         topics.astype(jnp.int32),

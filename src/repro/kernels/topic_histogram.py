@@ -111,6 +111,7 @@ def topic_histogram_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
+        name="topic_histogram",
     )(rank[:, None], z_old[:, None], z_new[:, None], inc[:, None])
     # combine tile partials: one scatter over (tiles * bt) rank rows —
     # T/bt x fewer scattered rows than the naive per-token scatter.

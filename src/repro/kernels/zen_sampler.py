@@ -209,6 +209,7 @@ def zen_sample_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="zen_sample",
     )(
         jnp.asarray([seed], jnp.int32),
         nwk_rows,
@@ -330,6 +331,7 @@ def zen_infer_sample_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="zen_infer_sample",
     )(
         nwk_rows,
         nkd_rows,

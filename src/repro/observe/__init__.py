@@ -7,7 +7,8 @@ of the related model-parallel serving work extends the same argument to
 admission knobs. This package is the shared measurement half of that
 loop: a lightweight counter/gauge/histogram registry with
 monotonic-clock span timers and a JSONL sink (``repro.observe.metrics``),
-plus two built-in emitters —
+trace spans on the profiler's clock (``span``, named ``zen.*``), plus two
+built-in emitters —
 
 * ``TrainTelemetry`` (``repro.observe.train_hooks``): a per-iteration
   ``TrainSession`` hook recording tokens/sec, per-backend row-nnz
@@ -31,6 +32,7 @@ from repro.observe.metrics import (  # noqa: F401
     SpanTimer,
     latency_percentile,
     nnz_row_stats,
+    span,
     summarize_latencies,
 )
 from repro.observe.serve_hooks import ServeTelemetry  # noqa: F401

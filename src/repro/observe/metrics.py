@@ -15,6 +15,12 @@ JSONL schema (DESIGN.md §8.2): every record is one flat JSON object with
   ``repro.observe.train_hooks`` / ``repro.observe.serve_hooks`` and the
   decision records in ``repro.autotune.policy``).
 
+Spans on the profiler's clock: ``span(name)`` opens a
+``jax.profiler.TraceAnnotation`` named ``zen.<name>``, so the program's own
+spans land in the same trace as the device's ops (DESIGN.md §8.5). With
+no profiler session active it costs one inactive ``TraceMe``. A
+``SpanTimer`` opens the same annotation around what it times.
+
 Percentile math: ``latency_percentile`` is THE nearest-rank definition
 used across the repo (``launch/serve_lda.py``, ``benchmarks/bench_infer.py``
 and the serving engine re-export it) and ``summarize_latencies`` is the
@@ -30,6 +36,7 @@ import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 
@@ -177,10 +184,20 @@ class Histogram:
         }
 
 
+SPAN_PREFIX = "zen."
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span named ``zen.<name>`` in the profiler's trace:
+    ``with span("engine.tick"): ...``."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+
+
 class SpanTimer:
     """Monotonic-clock span: ``with registry.timer("jit_rebuild"): ...``
     records the wall duration (seconds) into a histogram and, when the
-    registry has a sink, emits one ``kind="span"`` record per exit."""
+    registry has a sink, emits one ``kind="span"`` record per exit. The
+    same stretch is the trace span ``zen.<name>`` (``span``)."""
 
     def __init__(self, hist: Histogram, emit=None):
         self._hist = hist
@@ -189,11 +206,14 @@ class SpanTimer:
         self.last: Optional[float] = None
 
     def __enter__(self) -> "SpanTimer":
+        self._ann = span(self._hist.name)
+        self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> None:
         self.last = time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
         self._hist.observe(self.last)
         if self._emit is not None:
             self._emit({"kind": "span", "name": self._hist.name,

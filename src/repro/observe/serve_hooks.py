@@ -9,7 +9,8 @@ admission ticks or ``window_arrivals`` arrivals, whichever first.
 
 Every ``serve_window`` record carries the measured arrival process
 (inter-arrival times), queueing state (depth, slot occupancy, spills,
-ticks waited), the end-to-end latency summary of the requests that
+ticks waited), the bucket padding swept (``pad_share``), the end-to-end
+latency summary of the requests that
 finished inside the window, and the knob values in effect — exactly the
 inputs ``repro.autotune.ServeAutopilot`` derives ``tick_period`` /
 ``max_slot_wait`` / bucket widths from. All entry points are called by
@@ -17,7 +18,7 @@ the engine UNDER its lock; no locking here.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -46,6 +47,9 @@ class ServeTelemetry:
         self._reset_window()
         self._prev_arrival_t: Optional[float] = None
         self._windows_emitted = 0
+        # the engine's (tokens_swept, slot_tokens_swept) when the open
+        # window began: zero at construction, then each close's totals
+        self._swept_at_open: Tuple[int, int] = (0, 0)
 
     def _reset_window(self) -> None:
         self._ticks = 0
@@ -65,7 +69,6 @@ class ServeTelemetry:
                 (t_submit - self._prev_arrival_t) * 1e3)
         self._prev_arrival_t = t_submit
         self._doc_lens.append(int(doc_len))
-        self.registry.counter("serve.arrivals").inc()
 
     # -- tick-side ----------------------------------------------------------
     def record_tick(
@@ -79,14 +82,18 @@ class ServeTelemetry:
         max_slot_wait: int,
         bucket_widths: Sequence[int],
         model_version: int,
+        tokens_swept: int,
+        slot_tokens_swept: int,
     ) -> Optional[Dict[str, Any]]:
         """One admission tick (engine ``step``, under the engine lock).
 
         ``finished`` are the ``InferRequest``s this tick completed
         (``t_submit``/``t_done``/``ticks_waited`` are read off them);
-        ``spills_total`` is the engine's cumulative spill counter — the
-        window reports the delta. Returns the closed window's summary
-        record when this tick closed one, else None.
+        ``spills_total``, ``tokens_swept`` and ``slot_tokens_swept`` are
+        the engine's cumulative counters — the window reports their
+        deltas (``pad_share``: the share of swept slot tokens that were
+        padding). Returns the closed window's summary record when this
+        tick closed one, else None.
         """
         self._ticks += 1
         if self._spills_at_open is None:
@@ -97,22 +104,25 @@ class ServeTelemetry:
             if req.t_done and req.t_submit:
                 self._latencies_ms.append((req.t_done - req.t_submit) * 1e3)
             self._wait_ticks.append(int(req.ticks_waited))
-        self.registry.gauge("serve.queue_depth").set(queue_depth)
-        self.registry.gauge("serve.occupancy").set(occupancy)
         if (self._ticks < self.window_ticks
                 and len(self._doc_lens) < self.window_arrivals):
             return None
         return self._close_window(
             spills_total=int(spills_total),
+            swept=(int(tokens_swept), int(slot_tokens_swept)),
             tick_period=tick_period,
             max_slot_wait=max_slot_wait,
             bucket_widths=bucket_widths,
             model_version=model_version,
         )
 
-    def _close_window(self, *, spills_total: int, tick_period: float,
-                      max_slot_wait: int, bucket_widths: Sequence[int],
+    def _close_window(self, *, spills_total: int, swept: Tuple[int, int],
+                      tick_period: float, max_slot_wait: int,
+                      bucket_widths: Sequence[int],
                       model_version: int) -> Dict[str, Any]:
+        tokens = swept[0] - self._swept_at_open[0]
+        slot_tokens = swept[1] - self._swept_at_open[1]
+        self._swept_at_open = swept
         inter = sorted(self._interarrivals_ms)
         waits = sorted(self._wait_ticks)
         depths = self._queue_depths
@@ -139,6 +149,8 @@ class ServeTelemetry:
                                if waits else 0.0),
             "wait_ticks_max": int(max(waits)) if waits else 0,
             "spills": spills_total - (self._spills_at_open or 0),
+            "pad_share": (1.0 - tokens / slot_tokens if slot_tokens
+                          else None),
             "knobs": {
                 "tick_period": tick_period,
                 "max_slot_wait": int(max_slot_wait),
@@ -146,7 +158,6 @@ class ServeTelemetry:
             },
             "model_version": int(model_version),
         }
-        self.registry.counter("serve.windows").inc()
         self.registry.emit(rec)
         self.last_window = rec
         self._reset_window()
@@ -155,7 +166,6 @@ class ServeTelemetry:
     # -- decision + router emitters -----------------------------------------
     def emit_decision(self, record: Dict[str, Any]) -> None:
         """Log one applied (or rejected) autopilot decision."""
-        self.registry.counter("serve.decisions").inc()
         self.registry.emit(record)
 
     def emit_router_loads(self, loads: Sequence[int]) -> None:

@@ -87,8 +87,6 @@ class TrainTelemetry:
             if k in metrics:
                 rec[k] = float(metrics[k])
         self.records.append(rec)
-        self.registry.gauge("train.tokens_per_s").set(rec["tokens_per_s"])
-        self.registry.counter("train.iterations").inc()
         self.registry.emit(rec)
         return rec
 
@@ -98,5 +96,4 @@ class TrainTelemetry:
 
     def emit_decision(self, record: Dict[str, Any]) -> None:
         """Log one applied (or rejected) autopilot decision."""
-        self.registry.counter("train.decisions").inc()
         self.registry.emit(record)

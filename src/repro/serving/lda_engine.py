@@ -80,6 +80,7 @@ from repro.core.types import LDAHyperParams
 # canonical home of the percentile math is the observability layer; the
 # import keeps the historical ``repro.serving.latency_percentile`` working
 from repro.observe.metrics import latency_percentile  # noqa: F401
+from repro.observe.metrics import span
 from repro.serving.sharded import (
     ShardedFrozenLDAModel,
     layout_key,
@@ -480,6 +481,12 @@ class LDAEngine:
         self.sweeps_run = 0  # jitted bucket sweeps/decodes executed
         self.reloads = 0
         self.spills = 0  # SLA bucket spills (max_slot_wait admissions)
+        self.ticks = 0  # admission ticks run (``step`` calls)
+        # bucket packing, summed over every bucket sweep: the real tokens
+        # of the active slots, and the tokens the kernel swept (every
+        # slot of the bucket, empty ones included)
+        self.tokens_swept = 0
+        self.slot_tokens_swept = 0
         # runtime SLA knobs: seeded from cfg, retuned in place by the
         # autopilot — cfg itself stays frozen (it is the *requested*
         # setup; these are the *current* values, see the properties below)
@@ -1027,6 +1034,8 @@ class LDAEngine:
             max_slot_wait=self._max_slot_wait,
             bucket_widths=self.bucket_widths,
             model_version=self._current.version,
+            tokens_swept=self.tokens_swept,
+            slot_tokens_swept=self.slot_tokens_swept,
         )
         if summary is None or self._autopilot is None:
             return
@@ -1199,8 +1208,13 @@ class LDAEngine:
         non-empty bucket, finish ripe chains. Latency mode: admit, run
         one fused RT-LDA decode per non-empty bucket — every admitted
         request finishes in the same tick.
+
+        The tick is the trace span ``zen.engine.tick``; its children are
+        ``zen.engine.admit`` and, per bucket, ``zen.engine.keys``,
+        ``zen.engine.sweep`` and ``zen.engine.finish``.
         """
-        with self._cv:
+        with self._cv, span("engine.tick"):
+            self.ticks += 1
             self._apply_pending_buckets()
             finished = (self._latency_step() if self.cfg.mode == "latency"
                         else self._throughput_step())
@@ -1210,77 +1224,98 @@ class LDAEngine:
                 self._cv.notify_all()
             return finished
 
+    def _count_sweep(self, bucket: _Bucket) -> None:
+        self.sweeps_run += 1
+        self.tokens_swept += sum(r.words.shape[0] for r in bucket.active
+                                 if r is not None)
+        self.slot_tokens_swept += bucket.length * len(bucket.active)
+
     def _latency_step(self) -> List[InferRequest]:
-        self._admit()
+        with span("engine.admit"):
+            self._admit()
         finished, self._instant = self._instant, []
         for bucket in self._buckets.values():
             if bucket.num_active == 0:
                 continue
             sm = bucket.slot_model  # pinned: in-flight = admitted model
-            z, n_kd = self._rtlda_fn(sm, bucket.length)(
-                bucket.words, bucket.mask, sm.model.n_wk, sm.model.n_k
-            )
-            self.sweeps_run += 1
-            z_host, n_kd_host = np.asarray(z), np.asarray(n_kd)
-            for slot, req in enumerate(bucket.active):
-                if req is None:
-                    continue
-                req.sweeps_done = req.num_sweeps
-                req.z = z_host[slot, : req.words.shape[0]].copy()
-                self._finish(req, bucket, slot, n_kd_host[slot],
-                             clear_mask=False)
-                finished.append(req)
-            bucket.mask = jnp.zeros_like(bucket.mask)  # one bulk clear
+            with span("engine.sweep"):
+                z, n_kd = self._rtlda_fn(sm, bucket.length)(
+                    bucket.words, bucket.mask, sm.model.n_wk, sm.model.n_k
+                )
+            self._count_sweep(bucket)
+            with span("engine.finish"):
+                z_host, n_kd_host = np.asarray(z), np.asarray(n_kd)
+                for slot, req in enumerate(bucket.active):
+                    if req is None:
+                        continue
+                    req.sweeps_done = req.num_sweeps
+                    req.z = z_host[slot, : req.words.shape[0]].copy()
+                    self._finish(req, bucket, slot, n_kd_host[slot],
+                                 clear_mask=False)
+                    finished.append(req)
+                bucket.mask = jnp.zeros_like(bucket.mask)  # one bulk clear
         return finished
 
     def _throughput_step(self) -> List[InferRequest]:
-        self._admit()
+        with span("engine.admit"):
+            self._admit()
         finished, self._instant = self._instant, []
         for bucket in self._buckets.values():
             if bucket.num_active == 0:
                 continue
-            keys = jnp.stack([
-                bucket.sweep_keys[s][bucket.active[s].sweeps_done]
-                if bucket.active[s] is not None
-                and bucket.sweep_keys[s] is not None
-                and bucket.active[s].sweeps_done
-                < bucket.active[s].num_sweeps
-                else self._dummy_key
-                for s in range(len(bucket.active))
-            ])
+            with span("engine.keys"):
+                keys = jnp.stack([
+                    bucket.sweep_keys[s][bucket.active[s].sweeps_done]
+                    if bucket.active[s] is not None
+                    and bucket.sweep_keys[s] is not None
+                    and bucket.active[s].sweeps_done
+                    < bucket.active[s].num_sweeps
+                    else self._dummy_key
+                    for s in range(len(bucket.active))
+                ])
             sm = bucket.slot_model  # pinned: in-flight = admitted model
-            bucket.z, bucket.n_kd = self._sweep_fn(sm, bucket.length)(
-                keys, bucket.words, bucket.mask, bucket.z, bucket.n_kd,
-                sm.model.n_wk, sm.model.n_k, sm.aux,
-            )
-            self.sweeps_run += 1
-            n_kd_host = None
-            for slot, req in enumerate(bucket.active):
-                if req is None:
-                    continue
-                req.sweeps_done += 1
-                want_sample = (
-                    req.burn_in >= 0
-                    and req.sweeps_done > req.burn_in
-                    and (req.sweeps_done - req.burn_in) % req.thin == 0
+            with span("engine.sweep"):
+                bucket.z, bucket.n_kd = self._sweep_fn(sm, bucket.length)(
+                    keys, bucket.words, bucket.mask, bucket.z, bucket.n_kd,
+                    sm.model.n_wk, sm.model.n_k, sm.aux,
                 )
-                ripe = req.sweeps_done >= req.num_sweeps
-                if want_sample or ripe:
-                    if n_kd_host is None:
-                        n_kd_host = np.asarray(bucket.n_kd)
-                    if want_sample:
-                        if req.theta_sum is None:
-                            req.theta_sum = np.zeros(
-                                sm.model.num_topics, np.float32
-                            )
-                        req.theta_sum += self._theta(req, n_kd_host[slot],
-                                                     sm.alpha_k)
-                        req.theta_samples += 1
-                if ripe:
-                    self._finish(req, bucket, slot,
-                                 None if n_kd_host is None
-                                 else n_kd_host[slot])
-                    finished.append(req)
+            self._count_sweep(bucket)
+            with span("engine.finish"):
+                finished.extend(self._finish_ripe(bucket, sm))
+        return finished
+
+    def _finish_ripe(self, bucket: _Bucket,
+                     sm: _ModelSlot) -> List[InferRequest]:
+        """After a throughput sweep: take the posterior-mean samples due
+        and finish the chains that ran all their sweeps."""
+        finished = []
+        n_kd_host = None
+        for slot, req in enumerate(bucket.active):
+            if req is None:
+                continue
+            req.sweeps_done += 1
+            want_sample = (
+                req.burn_in >= 0
+                and req.sweeps_done > req.burn_in
+                and (req.sweeps_done - req.burn_in) % req.thin == 0
+            )
+            ripe = req.sweeps_done >= req.num_sweeps
+            if want_sample or ripe:
+                if n_kd_host is None:
+                    n_kd_host = np.asarray(bucket.n_kd)
+                if want_sample:
+                    if req.theta_sum is None:
+                        req.theta_sum = np.zeros(
+                            sm.model.num_topics, np.float32
+                        )
+                    req.theta_sum += self._theta(req, n_kd_host[slot],
+                                                 sm.alpha_k)
+                    req.theta_samples += 1
+            if ripe:
+                self._finish(req, bucket, slot,
+                             None if n_kd_host is None
+                             else n_kd_host[slot])
+                finished.append(req)
         return finished
 
     def _theta(self, req: InferRequest, n_kd_row: np.ndarray,

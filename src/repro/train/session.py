@@ -57,6 +57,7 @@ from repro.core.exclusion import (
 from repro.core.hyper import duplicate_topic_map, merge_topics
 from repro.core.likelihood import joint_llh, predictive_llh
 from repro.core.types import CGSState, Corpus, LDAHyperParams
+from repro.observe.metrics import span
 from repro.train.schedule import ActionContext, Schedule, ScheduledAction
 
 
@@ -330,6 +331,7 @@ class SingleBoxPlan(ExecutionPlan):
         # compiled steps (``compiled_step``); the backend, hyper and
         # exclusion config are baked in, so changing any of them clears it
         self._compiled: Dict[Any, Any] = {}
+        self.compiles = 0  # programs compiled by ``compiled_step``
 
     # -- lifecycle ---------------------------------------------------------
     def init(self, rng: jax.Array, init_topics=None) -> CGSState:
@@ -366,15 +368,17 @@ class SingleBoxPlan(ExecutionPlan):
                                   self._sweep_knobs(state), self._aux)
 
     def step(self, state: CGSState) -> CGSState:
-        compiled, args = self.compiled_step(state)
-        return compiled(*args)
+        with span("train.step"):
+            compiled, args = self.compiled_step(state)
+            return compiled(*args)
 
     def compiled_step(self, state: CGSState):
         """The step as one compiled program, and its arguments: ``(exe,
         args)`` with ``exe(*args)`` the next state. Compiled once per
         sampler knobs and argument shapes (padded-sparse backends resolve
         their row widths from ``state`` first); ``exe`` exposes
-        ``memory_analysis()`` and ``as_text()``."""
+        ``memory_analysis()`` and ``as_text()``. A compile is the trace
+        span ``zen.train.compile`` and counts in ``compiles``."""
         knobs = self._sweep_knobs(state)
         state = dataclasses.replace(
             state, iteration=jnp.asarray(state.iteration, jnp.int32)
@@ -386,34 +390,42 @@ class SingleBoxPlan(ExecutionPlan):
         if exe is None:
             if len(self._compiled) >= 8:  # padded-sparse widths drift
                 self._compiled.pop(next(iter(self._compiled)))
-            exe = jax.jit(functools.partial(self._step, knobs)).lower(
-                *args).compile()
+            with span("train.compile"):
+                exe = jax.jit(functools.partial(self._step, knobs)).lower(
+                    *args).compile()
+            self.compiles += 1
             self._compiled[sig] = exe
         return exe, args
 
     def _step(self, knobs, state: CGSState, word, doc, aux) -> CGSState:
+        """One sweep and count update. Its ops carry the named scopes
+        ``zen.sweep`` (with ``zen.relayout`` inside the kernel wrappers),
+        ``zen.delta_counts`` and ``zen.update`` in their HLO metadata."""
         c = dataclasses.replace(self.corpus, word=word, doc=doc)
         h = self.hyper
         key = jax.random.fold_in(state.rng, 2**20 + state.iteration)
         mask = active_mask(state, self._excl, key)
-        z_new_all = self.backend.sweep(state, c, h, knobs, aux)
+        with jax.named_scope("zen.sweep"):
+            z_new_all = self.backend.sweep(state, c, h, knobs, aux)
         z_new = jnp.where(mask, z_new_all, state.topic)
-        d_wk, d_kd, d_k = counts_lib.delta_counts(
-            c.word, c.doc, state.topic, z_new, c.num_words, c.num_docs,
-            h.num_topics,
-        )
-        i_new, t_new = update_exclusion_stats(state, z_new, mask)
-        return CGSState(
-            topic=z_new,
-            prev_topic=state.topic,
-            n_wk=state.n_wk + d_wk,
-            n_kd=state.n_kd + d_kd,
-            n_k=state.n_k + d_k,
-            rng=state.rng,
-            iteration=state.iteration + 1,
-            stale_iters=i_new,
-            same_count=t_new,
-        )
+        with jax.named_scope("zen.delta_counts"):
+            d_wk, d_kd, d_k = counts_lib.delta_counts(
+                c.word, c.doc, state.topic, z_new, c.num_words, c.num_docs,
+                h.num_topics,
+            )
+        with jax.named_scope("zen.update"):
+            i_new, t_new = update_exclusion_stats(state, z_new, mask)
+            return CGSState(
+                topic=z_new,
+                prev_topic=state.topic,
+                n_wk=state.n_wk + d_wk,
+                n_kd=state.n_kd + d_kd,
+                n_k=state.n_k + d_k,
+                rng=state.rng,
+                iteration=state.iteration + 1,
+                stale_iters=i_new,
+                same_count=t_new,
+            )
 
     # -- metrics -----------------------------------------------------------
     def llh(self, state: CGSState) -> float:

@@ -191,7 +191,7 @@ def test_serve_telemetry_window_closes_on_arrival_budget(tmp_path):
         summary = tel.record_tick(
             queue_depth=1, occupancy=2, finished=[], spills_total=0,
             tick_period=0.001, max_slot_wait=0, bucket_widths=(32, 64),
-            model_version=1,
+            model_version=1, tokens_swept=0, slot_tokens_swept=0,
         ) or summary
     assert summary is not None and summary["kind"] == "serve_window"
     assert summary["arrivals"] == 4

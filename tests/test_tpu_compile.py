@@ -5,11 +5,11 @@ count matrix), through the ``ops`` wrappers with ``interpret=False``.
 Nothing runs: the TPU compiler, installed with jax, compiles for a
 described v5e, which catches what interpret mode cannot — misaligned
 tiles, unsupported Mosaic ops, VMEM or SMEM overuse. Each compile must
-keep its kernel as a ``tpu_custom_call``. The topology is described
-inside a module fixture (never at import), so only the worker that runs
-these tests loads the TPU library; the persistent compile cache is off
-around the compiles (an entry written for a described chip cannot be
-read back here).
+keep its kernel as a ``tpu_custom_call`` under its ``pallas_call`` name.
+The topology is described inside a module fixture (never at import), so
+only the worker that runs these tests loads the TPU library; the
+persistent compile cache is off around the compiles (an entry written for
+a described chip cannot be read back here).
 """
 import jax
 import jax.numpy as jnp
@@ -107,14 +107,42 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("name", list(CASES))
-def test_kernel_compiles_for_v5e(name, one_chip):
+# each case's pallas_call name, which its op_name carries
+KERNEL_NAMES = {
+    "zen_sample": "zen_sample", "zen_infer_sample": "zen_infer_sample",
+    "zen_fused_sample": "zen_fused_sample",
+    "zen_fused_sample_smoke_t": "zen_fused_sample",
+    "zen_fused_infer_sample": "zen_fused_infer_sample",
+    "cdf_row_search": "cdf_search", "cdf_row_search_smoke_t": "cdf_search",
+    "sparse_row_sample": "sparse_row", "topic_histogram": "topic_histogram",
+}
+
+
+def _compile(name, one_chip):
     fn, arg_specs = CASES[name]
     args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
             for shape, dt in arg_specs]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), name
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip):
+    compiled = _compile(name, one_chip)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, name
+    assert f"/{KERNEL_NAMES[name]}/pallas_call" in text, name
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < 16 * 2**30, (name, used)
+
+
+@pytest.mark.parametrize("name", ["zen_fused_sample",
+                                  "zen_fused_infer_sample"])
+def test_fused_relayout_keeps_its_scope_on_v5e(name, one_chip):
+    """The count matrices' pad and row view before a fused kernel keep the
+    named scope ``zen.relayout`` through the v5e compiler."""
+    lines = _compile(name, one_chip).as_text().splitlines()
+    scoped = [ln for ln in lines if "zen.relayout" in ln]
+    assert any(" pad(" in ln for ln in scoped), name
+    assert any(" reshape(" in ln for ln in scoped), name
