@@ -313,7 +313,9 @@ class _Bucket:
         self.z = jnp.zeros((slots, length), jnp.int32)
         self.n_kd = jnp.zeros((slots, num_topics), jnp.int32)
         self.active: List[Optional[InferRequest]] = [None] * slots
-        self.sweep_keys: List[Optional[jax.Array]] = [None] * slots
+        # per slot: the chain's per-sweep keys as host bits, shape
+        # (num_sweeps, *key_data_shape) uint32 (None when the slot is empty)
+        self.sweep_keys: List[Optional[np.ndarray]] = [None] * slots
         self.slot_model: Optional[_ModelSlot] = None
 
     def free_slot(self) -> Optional[int]:
@@ -473,7 +475,10 @@ class LDAEngine:
             for length in sorted(cfg.buckets)
         }
         self._base_key = jax.random.key(seed)
-        self._dummy_key = jax.random.key(0)
+        # the key bits every sweep hands to a slot without a live chain
+        dummy_key = jax.random.key(0)
+        self._key_impl = jax.random.key_impl(dummy_key)
+        self._dummy_bits = np.asarray(jax.random.key_data(dummy_key))
         self.queue: List[InferRequest] = []
         self._instant: List[InferRequest] = []  # empty docs: done at submit
         self._uid = 0
@@ -1143,16 +1148,15 @@ class LDAEngine:
             return
         # same schedule as cgs_infer: z0 from the request key itself, sweep
         # j from split(key)[j]; randint/uniform draws are prefix-stable in
-        # the padded length, so the bucket width never changes the chain
+        # the padded length, so the bucket width never changes the chain.
+        # One device->host copy brings z0 and the sweep keys' host bits.
         z0 = jax.random.randint(req.key, (l,), 0, k, dtype=jnp.int32)
-        z0_np = np.asarray(z0)
+        z0_np, bucket.sweep_keys[slot] = jax.device_get((
+            z0, jax.random.key_data(jax.random.split(req.key, req.num_sweeps))
+        ))
         n_kd = np.bincount(z0_np[:n], minlength=k).astype(np.int32)
         bucket.z = bucket.z.at[slot].set(z0)
         bucket.n_kd = bucket.n_kd.at[slot].set(jnp.asarray(n_kd))
-        bucket.sweep_keys[slot] = (
-            jax.random.split(req.key, req.num_sweeps)
-            if req.num_sweeps > 0 else None
-        )
 
     # -- the jitted per-bucket programs -------------------------------------
     def _sweep_fn(self, slot_model: _ModelSlot, length: int):
@@ -1168,10 +1172,11 @@ class LDAEngine:
                     slot_model.aux,
                 )
                 return slot_model.sweep_fns[length]
-            backend, knobs = self.backend, self._knobs
+            backend, knobs, impl = self.backend, self._knobs, self._key_impl
             hyper = slot_model.model.hyper
 
-            def fn(keys, words, mask, z, n_kd, n_wk, n_k, aux):
+            def fn(key_bits, words, mask, z, n_kd, n_wk, n_k, aux):
+                keys = jax.random.wrap_key_data(key_bits, impl=impl)
                 z_new = backend.infer_sweep(
                     keys, words, mask, z, n_kd, n_wk, n_k, hyper, knobs, aux
                 )
@@ -1264,15 +1269,7 @@ class LDAEngine:
             if bucket.num_active == 0:
                 continue
             with span("engine.keys"):
-                keys = jnp.stack([
-                    bucket.sweep_keys[s][bucket.active[s].sweeps_done]
-                    if bucket.active[s] is not None
-                    and bucket.sweep_keys[s] is not None
-                    and bucket.active[s].sweeps_done
-                    < bucket.active[s].num_sweeps
-                    else self._dummy_key
-                    for s in range(len(bucket.active))
-                ])
+                keys = self._sweep_key_bits(bucket)
             sm = bucket.slot_model  # pinned: in-flight = admitted model
             with span("engine.sweep"):
                 bucket.z, bucket.n_kd = self._sweep_fn(sm, bucket.length)(
@@ -1283,6 +1280,18 @@ class LDAEngine:
             with span("engine.finish"):
                 finished.extend(self._finish_ripe(bucket, sm))
         return finished
+
+    def _sweep_key_bits(self, bucket: _Bucket) -> np.ndarray:
+        """The bucket sweep's keys as one host array of key bits, shape
+        (slots, *key_data_shape) uint32: row ``sweeps_done`` of each live
+        chain's table, the dummy bits for every other slot."""
+        bits = np.repeat(self._dummy_bits[None], len(bucket.active), axis=0)
+        for s, req in enumerate(bucket.active):
+            table = bucket.sweep_keys[s]
+            if (req is not None and table is not None
+                    and req.sweeps_done < req.num_sweeps):
+                bits[s] = table[req.sweeps_done]
+        return bits
 
     def _finish_ripe(self, bucket: _Bucket,
                      sm: _ModelSlot) -> List[InferRequest]:

@@ -194,13 +194,13 @@ def make_sharded_sweep_fn(backend, knobs, smodel: ShardedFrozenLDAModel,
     """The sharded analogue of the engine's jitted per-bucket sweep.
 
     Same call signature as the single-host program —
-    ``fn(keys, words, mask, z, n_kd, n_wk, n_k, aux)`` with ``words``
+    ``fn(key_bits, words, mask, z, n_kd, n_wk, n_k, aux)`` with ``words``
     already in shard space (``ShardedFrozenLDAModel.relabel``) — so the
     engine's stepping loop is layout-blind. Inside the ``shard_map``
     every device computes the full (B, L) batch against its own row
     block, keeps the tokens it owns, and ``psum``\\ s assignments; keys
-    cross the shard boundary as raw uint32 bits (extended key dtypes and
-    ``shard_map`` disagree across jax versions)."""
+    arrive and cross the shard boundary as raw uint32 bits (extended key
+    dtypes and ``shard_map`` disagree across jax versions)."""
     mesh, hyper = smodel.mesh, smodel.hyper
     wps, w_total = smodel.words_per_shard, smodel.num_words
     k = smodel.num_topics
@@ -227,11 +227,8 @@ def make_sharded_sweep_fn(backend, knobs, smodel: ShardedFrozenLDAModel,
         out_specs=P(),
     )
 
-    def fn(keys, words, mask, z, n_kd, n_wk, n_k, aux_a):
-        z_sum = sharded(
-            jax.random.key_data(keys), words, mask, z, n_kd, n_wk, n_k,
-            aux_a,
-        )
+    def fn(key_bits, words, mask, z, n_kd, n_wk, n_k, aux_a):
+        z_sum = sharded(key_bits, words, mask, z, n_kd, n_wk, n_k, aux_a)
         z_new = jnp.where(mask, z_sum, z)
         onehot = (
             jax.nn.one_hot(z_new, k, dtype=jnp.int32) * mask[..., None]

@@ -186,6 +186,63 @@ def test_zen_pallas_sweeps_stay_random_with_vacant_slots():
         assert np.abs(oracle - theta).sum() < 0.2
 
 
+@pytest.mark.parametrize("algorithm", ["zen", "zen_cdf", "zen_pallas"])
+def test_sweep_keys_are_one_host_array_of_chain_key_bits(algorithm):
+    """The keys a bucket sweep receives: one host uint32 array whose row
+    for a live chain is ``key_data(split(key, num_sweeps)[sweeps_done])``
+    and, for every other slot (never used, or evacuated by a cancel), the
+    bits of ``key(0)`` — no per-slot device array is built."""
+    model = _sharp_model()
+    rng = np.random.default_rng(13)
+
+    def doc():
+        return rng.integers(0, 40, size=12).astype(np.int32)
+
+    eng = LDAEngine(
+        model,
+        LDAServeConfig(buckets=(16,), max_batch=4, num_sweeps=5,
+                       algorithm=algorithm),
+        seed=0,
+    )
+    seen = []
+    sweep_fn = eng._sweep_fn
+
+    def recording_sweep_fn(sm, length):
+        fn = sweep_fn(sm, length)
+
+        def record(keys, *args):
+            seen.append(keys)
+            return fn(keys, *args)
+
+        return record
+
+    eng._sweep_fn = recording_sweep_fn
+    key_a, key_b, key_c = (jax.random.key(s) for s in (21, 22, 23))
+    eng.submit(doc(), num_sweeps=2)  # slot 0: ripe after two ticks
+    eng.submit(doc(), key=key_a)  # slot 1
+    cancelled = eng.submit_async(doc(), key=key_c)  # slot 2
+    eng.step()
+    eng.step()
+    assert eng.cancel(cancelled)  # slot 2 evacuated after two sweeps
+    eng.submit(doc(), key=key_b)  # takes slot 0, freed by the ripe chain
+    eng.step()
+
+    def bits(key, sweep):
+        return np.asarray(jax.random.key_data(jax.random.split(key, 5)[sweep]))
+
+    dummy = np.asarray(jax.random.key_data(jax.random.key(0)))
+    keys = seen[-1]
+    assert len(seen) == 3
+    assert all(type(k) is np.ndarray and k.dtype == np.uint32 for k in seen)
+    assert keys.shape == (4,) + dummy.shape
+    np.testing.assert_array_equal(keys[0], bits(key_b, 0))  # first sweep
+    np.testing.assert_array_equal(keys[1], bits(key_a, 2))  # mid-chain
+    np.testing.assert_array_equal(keys[2], dummy)  # cancelled mid-chain
+    np.testing.assert_array_equal(keys[3], dummy)  # never used
+    np.testing.assert_array_equal(seen[0][1], bits(key_a, 0))
+    np.testing.assert_array_equal(seen[1][2], bits(key_c, 1))
+
+
 def test_every_registered_backend_serves():
     """The registry contract: every backend serves through the default
     ``infer_sweep`` derivation (overrides or not) with sane output."""
