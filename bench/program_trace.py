@@ -40,6 +40,7 @@ from bench.trace import WINDOW_SPAN, Event, _clip, _union, op_name
 
 SPAN_PREFIX = "zen."
 TICK_SPAN = "zen.engine.tick"
+STEP_SCOPE = "zen.sweep"  # the outermost scope of a scoped train step
 UNATTRIBUTED = "unattributed"
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{\s*$")
@@ -273,3 +274,17 @@ def seconds_by_scope(op_s: Dict[str, float],
         key = scopes.get(op_name(text), UNATTRIBUTED)
         out[key] = out.get(key, 0.0) + sec
     return out
+
+
+def step_ms_by_scope(ctx):
+    """Device milliseconds per step and chip of each scope of a train
+    job's compiled step, from its reader context (the reduced trace,
+    ``scopes``, ``steps``, ``chips``). None where the step has no
+    ``zen.sweep`` scope (the mesh step): the kernel wrappers'
+    ``zen.relayout`` would be its only scope, and ``scope_map`` would
+    spread it over every instruction."""
+    scopes = ctx.get("scopes")
+    if not scopes or not ctx["steps"] or STEP_SCOPE not in scopes.values():
+        return None
+    return {scope: sec / ctx["steps"] * 1e3 / ctx["chips"] for scope, sec
+            in seconds_by_scope(ctx["trace"].op_s, scopes).items()}

@@ -8,8 +8,10 @@ the cell's configuration (``bench/configs/<config>.json``), its traffic mix
 (``bench/traffic/<traffic>.json``, whose ``job`` names the module that
 drives it), the limits of its checks (``bench/limits/<workload>.json``) and,
 with ``--trace 1``, one reader per per-layer metric
-(``bench/metrics/<metric>.py``). Without a TPU, or with fewer chips than the
-cell asks for, it exits with code 3 before any work.
+(``bench/metrics/<metric>.py``), which reads the reduced trace and the
+job's ``layer_ctx`` with ``chips``, the cell's chip count. Without a TPU,
+or with fewer chips than the cell asks for, it exits with code 3 before
+any work.
 """
 from __future__ import annotations
 
@@ -101,7 +103,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     ``overrides`` (tests only) maps ``cfg``/``traffic``/``limits`` to
     entries that replace the files' values, for a run at a small size."""
-    from bench import harness, trace as trace_lib, work
+    from bench import harness, program_trace, trace as trace_lib, work
 
     bench, wl, cfg, traffic, limits = load_cell(workload)
     for part, extra in (overrides or {}).items():
@@ -132,7 +134,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             trace_lib.find_xplane(ctx.trace_dir)))
         shutil.rmtree(ctx.trace_dir, ignore_errors=True)
         lctx = dict(out["layer_ctx"], trace=red, cfg=cfg, traffic=traffic,
+                    chips=wl["chips"],
                     peaks=work.peaks(dev["kind"]) if require_chip else None)
+        by_scope = program_trace.step_ms_by_scope(lctx)
+        if by_scope:
+            ctx.log(device_ms_by_scope=by_scope)
         for m in cell_metrics(bench, workload, "per_layer"):
             value = metric_reader(m["name"]).read(lctx)
             if value is not None:
