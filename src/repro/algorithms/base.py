@@ -12,10 +12,12 @@ counts/corpus substrate:
   owns masking (token exclusion), the delta merge, and the state update, so
   a backend is *only* the per-token draw.
 * ``cell_sweep(key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-  num_words_pad, knobs) -> new_topics (T,)`` — the per-device form used
+  num_words, knobs) -> new_topics (T,)`` — the per-device form used
   inside ``shard_map`` by the distributed runtime: all ids are local to the
   device's (word-shard x doc-shard) cell and the count blocks are the local
-  shards. Only backends with ``supports_shard_map`` implement it.
+  shards, while ``num_words`` is the corpus's W (the smoothing mass counts
+  it, not the padded vocabulary). Only backends with
+  ``supports_shard_map`` implement it.
 * ``prepare_infer(n_wk, n_k, hyper, knobs) -> frozen aux`` /
   ``infer_sweep(keys, words, mask, z_old, n_kd, n_wk, n_k, hyper, knobs,
   aux) -> new_topics (B, L)`` — the *serving* form (frozen-model
@@ -177,7 +179,7 @@ class SamplerBackend:
 
     def cell_sweep(
         self, key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-        num_words_pad: int, knobs: SamplerKnobs,
+        num_words: int, knobs: SamplerKnobs,
     ) -> jax.Array:
         raise NotImplementedError(
             f"backend {self.name!r} does not support shard_map cells"
@@ -217,7 +219,7 @@ class SamplerBackend:
 
         ``num_words_total`` is the true (unsharded) vocabulary size W for
         any table whose math involves ``W * beta`` — the mesh-capable
-        path mirroring ``cell_sweep``'s ``num_words_pad``: under sharded
+        path mirroring ``cell_sweep``'s ``num_words``: under sharded
         serving ``n_wk`` is one shard's padded row block, so its leading
         dim is *not* W. None (single-host) means ``n_wk.shape[0]``."""
         return None
@@ -234,7 +236,7 @@ class SamplerBackend:
         counts; ``n_wk``/``n_k`` the frozen trained model. Returns new
         topics (B, L) (padded positions produce garbage the engine masks).
 
-        ``num_words_total`` mirrors ``cell_sweep``'s ``num_words_pad``:
+        ``num_words_total`` mirrors ``cell_sweep``'s ``num_words``:
         inside a sharded dispatch ``n_wk`` is the device's word-row block
         and ``words`` are shard-local row ids with ``mask`` true only on
         tokens the shard owns, so the ``W * beta`` denominator must come

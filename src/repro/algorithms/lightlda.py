@@ -45,13 +45,13 @@ class LightLDA(CellBackend):
 
     def cell_sweep(
         self, key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-        num_words_pad, knobs: SamplerKnobs,
+        num_words, knobs: SamplerKnobs,
     ):
         knobs = self.resolve_cell_knobs(knobs, hyper)
         doc_index = build_cell_doc_index(doc, mask, n_kd.shape[0])
         return lightlda_cell(
             key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-            num_words_pad, doc_index, knobs.max_kw, num_mh=knobs.num_mh,
+            num_words, doc_index, knobs.max_kw, num_mh=knobs.num_mh,
             use_kernel=kernel_dispatch(knobs.kernels),
             bt=knobs.bt, bs=knobs.bs,
         )

@@ -20,11 +20,11 @@ class SparseLDA(CellBackend):
 
     def cell_sweep(
         self, key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-        num_words_pad, knobs: SamplerKnobs,
+        num_words, knobs: SamplerKnobs,
     ):
         knobs = self.resolve_cell_knobs(knobs, hyper)
         return sparselda_cell(
-            key, word, doc, z_old, n_wk, n_kd, n_k, hyper, num_words_pad,
+            key, word, doc, z_old, n_wk, n_kd, n_k, hyper, num_words,
             knobs.max_kw, knobs.max_kd,
             use_kernel=kernel_dispatch(knobs.kernels),
             bt=knobs.bt, bs=knobs.bs,

@@ -55,7 +55,7 @@ def _bsearch_shared(cdf: jax.Array, targets: jax.Array) -> jax.Array:
 
 def zen_cdf_cell(
     key, word_l, doc_l, z_old, mask, n_wk_l, n_kd_l, n_k, hyper,
-    num_words_pad: int, max_kd: int,
+    num_words: int, max_kd: int,
     use_kernel: bool = False, bt: int = 256, bk: int = 512,
 ):
     """TPU-native faithful ZenLDA: precomputed CDFs + sparse doc rows.
@@ -72,7 +72,7 @@ def zen_cdf_cell(
     (zen_cdf's cross-path contract is statistical; see DESIGN.md §2.3).
     """
     k = hyper.num_topics
-    terms = precompute_zen_terms(n_k, hyper, num_words_pad)
+    terms = precompute_zen_terms(n_k, hyper, num_words)
 
     # --- per-iteration precompute (the "build tables" stage, Alg. 2 l.5-13)
     g_cdf = jnp.cumsum(terms.g_dense)  # (K,)
@@ -240,11 +240,11 @@ class ZenCdf(CellBackend):
 
     def cell_sweep(
         self, key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-        num_words_pad, knobs: SamplerKnobs,
+        num_words, knobs: SamplerKnobs,
     ):
         return zen_cdf_cell(
             key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-            num_words_pad, knobs.max_kd or DEFAULT_MAX_KD,
+            num_words, knobs.max_kd or DEFAULT_MAX_KD,
             use_kernel=kernel_dispatch(knobs.kernels),
             bt=knobs.bt, bk=knobs.bk,
         )

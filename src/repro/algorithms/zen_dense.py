@@ -30,7 +30,7 @@ def _searchsorted_rows(cdf: jax.Array, targets: jax.Array) -> jax.Array:
 
 def zen_dense_cell(
     key, word_l, doc_l, z_old, mask, n_wk_l, n_kd_l, n_k, hyper,
-    num_words_pad: int, method: str, token_chunk: int,
+    num_words: int, method: str, token_chunk: int,
 ):
     """Dense per-token (T, K) three-term probabilities; exact ¬dw."""
     k = hyper.num_topics
@@ -42,7 +42,7 @@ def zen_dense_cell(
         nd = (n_kd_l[d] - onehot).astype(jnp.float32)
         nk = (n_k[None, :] - onehot).astype(jnp.float32)
         alpha_k = hyper.alpha_k(n_k)[None, :]
-        w_beta = num_words_pad * hyper.beta
+        w_beta = num_words * hyper.beta
         t1 = 1.0 / (nk + w_beta)
         p = (alpha_k * hyper.beta + nw * alpha_k + nd * (nw + hyper.beta)) * t1
         if method == "gumbel":
@@ -72,11 +72,11 @@ class ZenDense(CellBackend):
 
     def cell_sweep(
         self, key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-        num_words_pad, knobs: SamplerKnobs,
+        num_words, knobs: SamplerKnobs,
     ):
         return zen_dense_cell(
             key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-            num_words_pad, knobs.sampling_method, knobs.token_chunk,
+            num_words, knobs.sampling_method, knobs.token_chunk,
         )
 
 

@@ -46,7 +46,7 @@ class ZenHybrid(CellBackend):
 
     def cell_sweep(
         self, key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-        num_words_pad, knobs: SamplerKnobs,
+        num_words, knobs: SamplerKnobs,
     ):
         knobs = self.resolve_cell_knobs(knobs, hyper)
         use_zen = hybrid_route_doc_side(
@@ -54,10 +54,10 @@ class ZenHybrid(CellBackend):
         )
         z_zen = get("zen_sparse").cell_sweep(
             key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-            num_words_pad, knobs,
+            num_words, knobs,
         )
         z_alt = get("sparselda").cell_sweep(
             key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-            num_words_pad, knobs,
+            num_words, knobs,
         )
         return jnp.where(use_zen, z_zen, z_alt)

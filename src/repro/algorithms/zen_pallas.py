@@ -118,7 +118,7 @@ class ZenPallas(CellBackend):
 
     def cell_sweep(
         self, key, word, doc, z_old, mask, n_wk, n_kd, n_k, hyper,
-        num_words_pad, knobs: SamplerKnobs,
+        num_words, knobs: SamplerKnobs,
     ):
         # lazy: keep pallas out of the import path of everything that
         # never selects this backend
@@ -132,7 +132,7 @@ class ZenPallas(CellBackend):
         # N_kd in int16), so the casts happen exactly once per sweep here.
         alpha_k = hyper.alpha_k(n_k)
         n_k_f = n_k.astype(jnp.float32)
-        w_beta = num_words_pad * hyper.beta
+        w_beta = num_words * hyper.beta
         n_wk_i = n_wk.astype(jnp.int32)
         n_kd_i = n_kd.astype(jnp.int32)
         use_kernel = kernel_dispatch(knobs.kernels)

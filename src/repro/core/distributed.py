@@ -33,6 +33,7 @@ matrices.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -201,11 +202,16 @@ def make_dist_step(
     cfg: DistConfig,
     words_per_shard: int,
     docs_per_shard: int,
+    num_words: int,
 ):
-    """Build the jitted distributed iteration fn: (state, data) -> state."""
+    """Build the jitted distributed iteration fn: (state, data) -> state.
+
+    ``num_words`` is the corpus's W, the smoothing mass's count (the grid
+    pads the vocabulary to ``words_per_shard`` a column). Its ops carry
+    the named scopes ``zen.sweep`` (with ``zen.relayout`` inside the
+    kernel wrappers), ``zen.delta_counts``, ``zen.exchange`` (the delta
+    ``psum``s) and ``zen.update`` in their HLO metadata."""
     data_axes, model = _axes(mesh)
-    all_axes = data_axes + (model,)
-    num_words_pad = words_per_shard * mesh.shape[model]
     state_spec, data_spec = _specs(mesh)
     k = hyper.num_topics
     backend = algorithms.get(cfg.algorithm)
@@ -257,10 +263,11 @@ def make_dist_step(
 
         # step 3: sample on stale counts — one registry-resolved call
         # (zen_dense / zen_cdf / zen_pallas / any future cell backend)
-        z_prop = backend.cell_sweep(
-            k_sample, word_l, doc_l, z_old, mask, n_wk_l, n_kd_l, n_k,
-            hyper, num_words_pad, knobs,
-        )
+        with jax.named_scope("zen.sweep"):
+            z_prop = backend.cell_sweep(
+                k_sample, word_l, doc_l, z_old, mask, n_wk_l, n_kd_l, n_k,
+                hyper, num_words, knobs,
+            )
         z_new = jnp.where(active, z_prop, z_old)
 
         # step 4: delta aggregation (§5.2) -------------------------------
@@ -271,42 +278,43 @@ def make_dist_step(
         # saturation — §Perf iteration l3)
         ddt = jnp.int32 if cfg.delta_dtype == "int32" else jnp.dtype(cfg.delta_dtype)
         changed = (z_new != z_old) & mask
-        inc = changed.astype(ddt)
-        d_wk = (
-            jnp.zeros(n_wk_l.shape, ddt)
-            .at[word_l, z_new].add(inc)
-            .at[word_l, z_old].add(-inc)
-        )
-        d_kd = (
-            jnp.zeros(n_kd_l.shape, ddt)
-            .at[doc_l, z_new].add(inc)
-            .at[doc_l, z_old].add(-inc)
-        )
-        d_wk = jax.lax.psum(d_wk, data_axes).astype(jnp.int32)
-        d_kd = jax.lax.psum(d_kd, (model,)).astype(jnp.int32)
-        # step 5: N_k from the word side only (paper Fig. 2 step 5)
-        d_k = jax.lax.psum(jnp.sum(d_wk, axis=0), model)
+        with jax.named_scope("zen.delta_counts"):
+            inc = changed.astype(ddt)
+            d_wk = (
+                jnp.zeros(n_wk_l.shape, ddt)
+                .at[word_l, z_new].add(inc)
+                .at[word_l, z_old].add(-inc)
+            )
+            d_kd = (
+                jnp.zeros(n_kd_l.shape, ddt)
+                .at[doc_l, z_new].add(inc)
+                .at[doc_l, z_old].add(-inc)
+            )
+        with jax.named_scope("zen.exchange"):
+            d_wk = jax.lax.psum(d_wk, data_axes).astype(jnp.int32)
+            d_kd = jax.lax.psum(d_kd, (model,)).astype(jnp.int32)
+            # step 5: N_k from the word side only (paper Fig. 2 step 5)
+            d_k = jax.lax.psum(jnp.sum(d_wk, axis=0), model)
 
-        # exclusion stats update
-        proc_changed = changed
-        new_i = jnp.where(active, 0, stale_i + 1)
-        new_t = jnp.where(
-            active, jnp.where(proc_changed, 0, same_t + 1), same_t
-        )
-
-        shp = state.topic.shape
-        new_n_kd = (n_kd_l.astype(jnp.int32) + d_kd).astype(n_kd_l.dtype)
-        return DistLDAState(
-            topic=z_new.reshape(shp),
-            prev_topic=z_old.reshape(shp),
-            n_wk=n_wk_l + d_wk,
-            n_kd=new_n_kd,
-            n_k=n_k + d_k,
-            stale_iters=new_i.reshape(shp),
-            same_count=new_t.reshape(shp),
-            iteration=state.iteration + 1,
-            rng=state.rng,
-        )
+        with jax.named_scope("zen.update"):
+            # exclusion stats update
+            new_i = jnp.where(active, 0, stale_i + 1)
+            new_t = jnp.where(
+                active, jnp.where(changed, 0, same_t + 1), same_t
+            )
+            shp = state.topic.shape
+            new_n_kd = (n_kd_l.astype(jnp.int32) + d_kd).astype(n_kd_l.dtype)
+            return DistLDAState(
+                topic=z_new.reshape(shp),
+                prev_topic=z_old.reshape(shp),
+                n_wk=n_wk_l + d_wk,
+                n_kd=new_n_kd,
+                n_k=n_k + d_k,
+                stale_iters=new_i.reshape(shp),
+                same_count=new_t.reshape(shp),
+                iteration=state.iteration + 1,
+                rng=state.rng,
+            )
 
     step = compat.shard_map(
         local_step, mesh, (state_spec, data_spec), state_spec,
@@ -351,12 +359,13 @@ def make_rebuild_counts(
 
 
 def make_dist_llh(
-    mesh: Mesh, hyper: LDAHyperParams, words_per_shard: int, docs_per_shard: int
+    mesh: Mesh, hyper: LDAHyperParams, words_per_shard: int,
+    docs_per_shard: int, num_words: int,
 ):
-    """Distributed predictive log-likelihood (paper footnote 6)."""
+    """Distributed predictive log-likelihood (paper footnote 6), with the
+    corpus's W (``num_words``) in the smoothing mass."""
     data_axes, model = _axes(mesh)
     all_axes = data_axes + (model,)
-    num_words_pad = words_per_shard * mesh.shape[model]
     state_spec, data_spec = _specs(mesh)
 
     def local(state: DistLDAState, data: DistLDAData) -> jax.Array:
@@ -372,7 +381,7 @@ def make_dist_llh(
         alpha_k = hyper.alpha_k(state.n_k)
         alpha_sum = jnp.sum(alpha_k)
         n_d = jnp.sum(state.n_kd, axis=-1).astype(jnp.float32)  # (Ds,)
-        w_beta = num_words_pad * hyper.beta
+        w_beta = num_words * hyper.beta
         theta = (state.n_kd[doc_l].astype(jnp.float32) + alpha_k[None, :]) / (
             n_d[doc_l][:, None] + alpha_sum
         )
@@ -401,44 +410,36 @@ def init_dist_state(
     init_topics: Optional[np.ndarray] = None,
     kd_dtype=jnp.int32,
 ) -> Tuple[DistLDAState, DistLDAData]:
-    """Build + device_put the sharded state from a host GridPartition."""
+    """Build the sharded state and data from a host GridPartition. Each
+    array goes to its shards in one transfer, and the initial topics are
+    drawn on the devices straight into their sharding: the values of an
+    eager ``randint(rng, (cells, e_cell), 0, K)``."""
     state_sh, data_sh = state_shardings(mesh)
     cells, e_cell = grid.word.shape
     k = hyper.num_topics
-    if init_topics is None:
-        init_topics = np.asarray(
-            jax.random.randint(rng, (cells, e_cell), 0, k, dtype=jnp.int32)
+    data = jax.device_put(DistLDAData(grid.word, grid.doc, grid.mask), data_sh)
+    if init_topics is not None:
+        init_topics = jax.device_put(np.asarray(init_topics, np.int32),
+                                     state_sh.topic)
+
+    @functools.partial(jax.jit, out_shardings=state_sh)
+    def fresh(rng, topic):
+        if topic is None:
+            topic = jax.random.randint(rng, (cells, e_cell), 0, k,
+                                       dtype=jnp.int32)
+        tok = jnp.zeros((cells, e_cell), jnp.int32)
+        # each output is a buffer of its own (the step donates the state,
+        # and the runtime rejects donating one buffer twice)
+        return DistLDAState(
+            topic=topic, prev_topic=topic,
+            n_wk=jnp.zeros((grid.num_words_padded, k), jnp.int32),
+            n_kd=jnp.zeros((grid.num_docs_padded, k), kd_dtype),
+            n_k=jnp.zeros((k,), jnp.int32),
+            stale_iters=tok, same_count=tok,
+            iteration=jnp.int32(0), rng=rng,
         )
-    data = DistLDAData(
-        word=jax.device_put(jnp.asarray(grid.word), data_sh.word),
-        doc=jax.device_put(jnp.asarray(grid.doc), data_sh.doc),
-        mask=jax.device_put(jnp.asarray(grid.mask), data_sh.mask),
-    )
-    topic = jax.device_put(jnp.asarray(init_topics, jnp.int32), state_sh.topic)
-    # distinct buffer: step functions donate the state, and donating one
-    # buffer twice (topic aliasing prev_topic) is rejected by the runtime
-    prev_topic = jax.device_put(jnp.asarray(init_topics, jnp.int32), state_sh.topic)
-    zeros_tok = jax.device_put(
-        jnp.zeros((cells, e_cell), jnp.int32), state_sh.stale_iters
-    )
-    zeros_tok2 = jax.device_put(
-        jnp.zeros((cells, e_cell), jnp.int32), state_sh.same_count
-    )
-    state = DistLDAState(
-        topic=topic,
-        prev_topic=prev_topic,
-        n_wk=jax.device_put(
-            jnp.zeros((grid.num_words_padded, k), jnp.int32), state_sh.n_wk
-        ),
-        n_kd=jax.device_put(
-            jnp.zeros((grid.num_docs_padded, k), kd_dtype), state_sh.n_kd
-        ),
-        n_k=jax.device_put(jnp.zeros((k,), jnp.int32), state_sh.n_k),
-        stale_iters=zeros_tok,
-        same_count=zeros_tok2,
-        iteration=jnp.int32(0),
-        rng=rng,
-    )
+
+    state = fresh(rng, init_topics)
     rebuild = make_rebuild_counts(
         mesh, hyper, grid.words_per_shard, grid.docs_per_shard
     )

@@ -26,6 +26,7 @@ what DBH+ buys (evaluated in benchmarks/bench_partition.py).
 from __future__ import annotations
 
 import dataclasses
+import heapq
 from typing import Dict, Tuple
 
 import numpy as np
@@ -142,6 +143,11 @@ class GridPartition:
     doc_perm: np.ndarray  # old -> new doc id (D,)
 
     @property
+    def num_words(self) -> int:
+        """The corpus's W (the smoothing mass counts these, not the pad)."""
+        return self.word_perm.shape[0]
+
+    @property
     def num_words_padded(self) -> int:
         return self.words_per_shard * self.model_parallel
 
@@ -156,16 +162,30 @@ class GridPartition:
 
 def _balanced_ranges(loads: np.ndarray, bins: int) -> np.ndarray:
     """Greedy LPT bin-packing: assign items (sorted by descending load) to
-    the least-loaded bin. Returns bin id per item. This is the DBH+ insight
-    applied to static ranges: hot items get spread first."""
+    the least-loaded bin, the lowest such bin on a tie. Returns bin id per
+    item. This is the DBH+ insight applied to static ranges: hot items get
+    spread first."""
     order = np.argsort(-loads, kind="stable")
-    bin_load = np.zeros(bins, dtype=np.int64)
-    assign = np.zeros(loads.shape[0], dtype=np.int32)
-    for it in order:
-        b = int(np.argmin(bin_load))
+    heap = [(0, b) for b in range(bins)]
+    assign = np.empty(loads.shape[0], dtype=np.int32)
+    for it, load in zip(order.tolist(), loads[order].tolist()):
+        least, b = heap[0]
         assign[it] = b
-        bin_load[b] += int(loads[it])
+        heapq.heapreplace(heap, (least + load, b))
     return assign
+
+
+def _relabel(assign: np.ndarray, bins: int) -> Tuple[np.ndarray, int]:
+    """Ids that make each bin's items contiguous, in their old order, in
+    ranges of one uniform width (the fullest bin's count)."""
+    counts = np.bincount(assign, minlength=bins)
+    per = int(counts.max())
+    order = np.argsort(assign, kind="stable")
+    starts = np.cumsum(counts) - counts
+    perm = np.empty(assign.shape[0], dtype=np.int64)
+    perm[order] = (assign[order].astype(np.int64) * per
+                   + np.arange(assign.shape[0]) - starts[assign[order]])
+    return perm, per
 
 
 def grid_partition(
@@ -189,44 +209,47 @@ def grid_partition(
         d_row = (_hash(np.arange(corpus.num_docs), 17) % data_parallel).astype(np.int32)
 
     # Relabel so each column's words are contiguous & uniform-width.
-    def relabel(assign: np.ndarray, bins: int) -> Tuple[np.ndarray, int]:
-        counts = np.bincount(assign, minlength=bins)
-        per = int(counts.max())
-        perm = np.empty(assign.shape[0], dtype=np.int64)
-        for b in range(bins):
-            ids = np.where(assign == b)[0]
-            perm[ids] = b * per + np.arange(ids.size)
-        return perm, per
-
-    word_perm, words_per_shard = relabel(w_col, model_parallel)
-    doc_perm, docs_per_shard = relabel(d_row, data_parallel)
-
-    new_word = word_perm[word]
-    new_doc = doc_perm[doc]
-    row = (new_doc // docs_per_shard).astype(np.int64)
-    col = (new_word // words_per_shard).astype(np.int64)
-    cell = row * model_parallel + col
+    word_perm, words_per_shard = _relabel(w_col, model_parallel)
+    doc_perm, docs_per_shard = _relabel(d_row, data_parallel)
     cells = data_parallel * model_parallel
 
-    cell_counts = np.bincount(cell, minlength=cells)
-    e_cell = int(cell_counts.max())
+    # Every token as one key whose bits order it by (cell, word, doc), or
+    # by (cell, doc, word), made of a word's and a document's part; tokens
+    # with equal keys are the same edge, so sorting the keys themselves
+    # lays the cells out, and each cell's keys start at its own bound.
+    wb = int(words_per_shard * model_parallel - 1).bit_length()
+    db = int(docs_per_shard * data_parallel - 1).bit_length()
+    cb = int(model_parallel - 1).bit_length()
+    row, col = np.divmod(np.arange(cells + 1), model_parallel)
+    if sort_tokens_by == "word":  # (row, new word, new doc)
+        word_part = word_perm << db
+        doc_part = (d_row.astype(np.int64) << (wb + db)) | doc_perm
+        bounds = (row << (wb + db)) | (col * words_per_shard << db)
+        shifts = (db, 0)
+    else:  # (row, col, new doc, new word)
+        word_part = (w_col.astype(np.int64) << (db + wb)) | word_perm
+        doc_part = ((d_row.astype(np.int64) << (cb + db + wb))
+                    | (doc_perm << wb))
+        bounds = (row << (cb + db + wb)) | (col << (db + wb))
+        shifts = (0, wb)
+    key = word_part[word]
+    key |= doc_part[doc]
+    key.sort()
+    starts = np.searchsorted(key, bounds)
+    counts = np.diff(starts)
+
+    e_cell = int(counts.max())
     e_cell = ((e_cell + e_cell_multiple - 1) // e_cell_multiple) * e_cell_multiple
     e_cell = max(e_cell, e_cell_multiple)
 
-    out_w = np.zeros((cells, e_cell), dtype=np.int32)
-    out_d = np.zeros((cells, e_cell), dtype=np.int32)
+    out_w = np.empty((cells, e_cell), dtype=np.int32)
+    out_d = np.empty((cells, e_cell), dtype=np.int32)
     out_m = np.zeros((cells, e_cell), dtype=bool)
-    order = np.lexsort(
-        (new_doc, new_word, cell) if sort_tokens_by == "word"
-        else (new_word, new_doc, cell)
-    )
-    sw, sd, sc = new_word[order], new_doc[order], cell[order]
-    starts = np.searchsorted(sc, np.arange(cells))
-    ends = np.searchsorted(sc, np.arange(cells) + 1)
     for c in range(cells):
-        n = ends[c] - starts[c]
-        out_w[c, :n] = sw[starts[c] : ends[c]]
-        out_d[c, :n] = sd[starts[c] : ends[c]]
+        n = int(counts[c])
+        part = key[starts[c]:starts[c] + n]
+        out_w[c, :n] = (part >> shifts[0]) & ((1 << wb) - 1)
+        out_d[c, :n] = (part >> shifts[1]) & ((1 << db) - 1)
         out_m[c, :n] = True
         # padding tokens point at the cell's own (word, doc) range so local
         # index arithmetic stays in-bounds; mask keeps them inert.
@@ -238,6 +261,5 @@ def grid_partition(
         word=out_w, doc=out_d, mask=out_m,
         data_parallel=data_parallel, model_parallel=model_parallel,
         words_per_shard=words_per_shard, docs_per_shard=docs_per_shard,
-        word_perm=word_perm.astype(np.int64),
-        doc_perm=doc_perm.astype(np.int64),
+        word_perm=word_perm, doc_perm=doc_perm,
     )

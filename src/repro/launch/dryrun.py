@@ -99,7 +99,7 @@ def build_step(cfg, kind: str, dims=None):
         def make(mesh):
             return make_dist_step(
                 mesh, hyper, dcfg, dims["words_per_shard"],
-                dims["docs_per_shard"],
+                dims["docs_per_shard"], dims["num_words"],
             )
 
         return make

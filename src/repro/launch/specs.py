@@ -190,7 +190,8 @@ def lda_cell_specs(
         word=tok, doc=tok, mask=_sds((cells, e_cell), jnp.bool_)
     )
     st_sh, dt_sh = state_shardings(mesh)
-    dims = {"words_per_shard": wps, "docs_per_shard": dps, "e_cell": e_cell}
+    dims = {"words_per_shard": wps, "docs_per_shard": dps, "e_cell": e_cell,
+            "num_words": cfg.num_words}
     return "lda", {"state": state, "data": data}, {
         "state": st_sh, "data": dt_sh,
     }, dims

@@ -524,7 +524,8 @@ class MeshPlan(ExecutionPlan):
     (data x model) grid, ``make_dist_step`` builds the shard_map iteration
     (paper Fig. 2), and structural events that change the compiled step's
     static workspace — exclusion enablement, row-capacity re-resolution —
-    rebuild the jitted step in place."""
+    rebuild the jitted step in place. The partition and ``init`` are the
+    host spans ``zen.mesh.partition`` and ``zen.mesh.init``."""
 
     def __init__(self, corpus: Corpus, hyper: LDAHyperParams, cfg: RunConfig,
                  mesh=None):
@@ -546,7 +547,8 @@ class MeshPlan(ExecutionPlan):
         self.mesh = mesh if mesh is not None else make_mesh(
             (rows, cols), ("data", "model")
         )
-        self.grid = grid_partition(corpus, rows, cols)
+        with span("mesh.partition"):
+            self.grid = grid_partition(corpus, rows, cols)
         # the user's explicit widths; 0 stays "auto" across re-resolutions
         self._user_kw, self._user_kd = cfg.max_kw, cfg.max_kd
         self.dcfg = DistConfig(
@@ -560,6 +562,8 @@ class MeshPlan(ExecutionPlan):
             bt=cfg.bt, bk=cfg.bk, bs=cfg.bs, kernels=cfg.kernels,
         )
         self._step_fn = None
+        self._compiled: Dict[Any, Any] = {}  # ``compiled_step``'s programs
+        self.compiles = 0  # programs compiled by ``compiled_step``
         self._data = None
         self._llh_fn = None
         self._rebuild_fn = None
@@ -574,23 +578,24 @@ class MeshPlan(ExecutionPlan):
             resolve_dist_row_pads,
         )
 
-        state, data = init_dist_state(
-            rng, self.mesh, self.grid, self.hyper,
-            init_topics=init_topics, kd_dtype=self._kd_dtype,
-        )
-        self._data = data
-        # shard-relative padded-row capacities from the *init* counts; the
-        # repad action re-resolves them on the rebuild cadence
-        self.dcfg = resolve_dist_row_pads(state, self.dcfg)
-        self._llh_fn = make_dist_llh(
-            self.mesh, self.hyper, self.grid.words_per_shard,
-            self.grid.docs_per_shard,
-        )
-        self._rebuild_fn = make_rebuild_counts(
-            self.mesh, self.hyper, self.grid.words_per_shard,
-            self.grid.docs_per_shard,
-        )
-        self._build_step()
+        with span("mesh.init"):
+            state, data = init_dist_state(
+                rng, self.mesh, self.grid, self.hyper,
+                init_topics=init_topics, kd_dtype=self._kd_dtype,
+            )
+            self._data = data
+            # shard-relative padded-row capacities from the *init* counts;
+            # the repad action re-resolves them on the rebuild cadence
+            self.dcfg = resolve_dist_row_pads(state, self.dcfg)
+            self._llh_fn = make_dist_llh(
+                self.mesh, self.hyper, self.grid.words_per_shard,
+                self.grid.docs_per_shard, self.grid.num_words,
+            )
+            self._rebuild_fn = make_rebuild_counts(
+                self.mesh, self.hyper, self.grid.words_per_shard,
+                self.grid.docs_per_shard,
+            )
+            self._build_step()
         return state
 
     def _build_step(self) -> None:
@@ -598,11 +603,33 @@ class MeshPlan(ExecutionPlan):
 
         self._step_fn = make_dist_step(
             self.mesh, self.hyper, self.dcfg, self.grid.words_per_shard,
-            self.grid.docs_per_shard,
+            self.grid.docs_per_shard, self.grid.num_words,
         )
+        self._compiled.clear()
 
     def step(self, state):
-        return self._step_fn(state, self._data)
+        with span("train.step"):
+            exe, args = self.compiled_step(state)
+            return exe(*args)
+
+    def compiled_step(self, state):
+        """The step as one compiled program, and its arguments: ``(exe,
+        args)`` with ``exe(*args)`` the next state (it donates ``state``).
+        Compiled once per argument shapes, dtypes and shardings, until a
+        structural event rebuilds the step; ``exe`` exposes
+        ``memory_analysis()`` and ``as_text()``. A compile is the trace
+        span ``zen.train.compile`` and counts in ``compiles``."""
+        args = (state, self._data)
+        sig = (jax.tree.structure(args),
+               tuple((x.shape, x.dtype, x.sharding)
+                     for x in jax.tree.leaves(args)))
+        exe = self._compiled.get(sig)
+        if exe is None:
+            with span("train.compile"):
+                exe = self._step_fn.lower(*args).compile()
+            self.compiles += 1
+            self._compiled[sig] = exe
+        return exe, args
 
     # -- metrics -----------------------------------------------------------
     def llh(self, state) -> float:
@@ -694,7 +721,7 @@ class MeshPlan(ExecutionPlan):
         # the compiled step, llh, and rebuild all close over hyper
         self._llh_fn = make_dist_llh(
             self.mesh, hyper, self.grid.words_per_shard,
-            self.grid.docs_per_shard,
+            self.grid.docs_per_shard, self.grid.num_words,
         )
         self._rebuild_fn = make_rebuild_counts(
             self.mesh, hyper, self.grid.words_per_shard,

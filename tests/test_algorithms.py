@@ -121,7 +121,7 @@ def test_dist_config_resolves_same_registry_entry(key, tiny_corpus, tiny_hyper):
     state, data = init_dist_state(key, mesh, grid, tiny_hyper)
     step = make_dist_step(
         mesh, tiny_hyper, DistConfig(algorithm="zen_pallas", max_kd=8),
-        grid.words_per_shard, grid.docs_per_shard,
+        grid.words_per_shard, grid.docs_per_shard, grid.num_words,
     )
     for _ in range(2):
         state = step(state, data)
@@ -140,7 +140,7 @@ def test_dist_step_rejects_single_box_only_backends(key, tiny_corpus, tiny_hyper
     with pytest.raises(ValueError, match="shard_map"):
         make_dist_step(
             mesh, tiny_hyper, DistConfig(algorithm="std"),
-            grid.words_per_shard, grid.docs_per_shard,
+            grid.words_per_shard, grid.docs_per_shard, grid.num_words,
         )
 
 

@@ -46,8 +46,10 @@ grid = grid_partition(corpus, 2, 2)
 E = int(grid.mask.sum())
 state, data = init_dist_state(jax.random.key(0), mesh, grid, hyper)
 step = make_dist_step(mesh, hyper, DistConfig(algorithm='{alg}', max_kd=8),
-                      grid.words_per_shard, grid.docs_per_shard)
-llh = make_dist_llh(mesh, hyper, grid.words_per_shard, grid.docs_per_shard)
+                      grid.words_per_shard, grid.docs_per_shard,
+                      grid.num_words)
+llh = make_dist_llh(mesh, hyper, grid.words_per_shard, grid.docs_per_shard,
+                    grid.num_words)
 l0 = float(llh(state, data))
 for _ in range(10):
     state = step(state, data)
@@ -73,7 +75,8 @@ for dd in ('int16', 'int8'):
     step = make_dist_step(mesh, hyper,
                           DistConfig(algorithm='zen_cdf', max_kd=8,
                                      delta_dtype=dd),
-                          grid.words_per_shard, grid.docs_per_shard)
+                          grid.words_per_shard, grid.docs_per_shard,
+                          grid.num_words)
     for _ in range(6):
         state = step(state, data)
     assert int(jnp.sum(state.n_k)) == E, dd
@@ -90,7 +93,8 @@ grid_a = grid_partition(corpus, 2, 2)
 E = int(grid_a.mask.sum())
 state, data = init_dist_state(jax.random.key(0), mesh_a, grid_a, hyper)
 step = make_dist_step(mesh_a, hyper, DistConfig(algorithm='zen_cdf', max_kd=8),
-                      grid_a.words_per_shard, grid_a.docs_per_shard)
+                      grid_a.words_per_shard, grid_a.docs_per_shard,
+                      grid_a.num_words)
 for _ in range(4):
     state = step(state, data)
 # checkpoint = per-token assignments keyed by ORIGINAL (word, doc) ids
@@ -132,7 +136,8 @@ for shape in [(1, 4), (4, 1)]:
                                   np.asarray(state.n_k))
     step_b = make_dist_step(mesh_b, hyper,
                             DistConfig(algorithm='zen_cdf', max_kd=8),
-                            grid_b.words_per_shard, grid_b.docs_per_shard)
+                            grid_b.words_per_shard, grid_b.docs_per_shard,
+                            grid_b.num_words)
     state_b = step_b(state_b, data_b)  # continues training
     assert int(jnp.sum(state_b.n_k)) == E
 print('ELASTIC OK')
@@ -146,7 +151,8 @@ grid = grid_partition(corpus, 2, 2)  # pod*data rows = 2
 E = int(grid.mask.sum())
 state, data = init_dist_state(jax.random.key(0), mesh, grid, hyper)
 step = make_dist_step(mesh, hyper, DistConfig(algorithm='zen_cdf', max_kd=8),
-                      grid.words_per_shard, grid.docs_per_shard)
+                      grid.words_per_shard, grid.docs_per_shard,
+                      grid.num_words)
 for _ in range(4):
     state = step(state, data)
 assert int(jnp.sum(state.n_k)) == E

@@ -89,3 +89,93 @@ def test_word_sorted_within_cell(skewed):
     for c_ in range(4):
         ws = grid.word[c_][grid.mask[c_]]
         assert (np.diff(ws) >= 0).all()
+
+
+# -- grid_partition against the plain algorithm it replaced --------------------
+
+def _oracle_grid(corpus, rows, cols, balance, sort_tokens_by):
+    """The partition as first written: an LPT loop over ``argmin``, a
+    relabelling loop per bin, one three-key ``lexsort`` and a fill loop.
+    Returns (word, doc, mask, word_perm, doc_perm, words_per_shard,
+    docs_per_shard)."""
+    from repro.core.graph import _hash
+
+    word, doc = np.asarray(corpus.word), np.asarray(corpus.doc)
+
+    def lpt(loads, bins):
+        bin_load = np.zeros(bins, dtype=np.int64)
+        assign = np.zeros(loads.shape[0], dtype=np.int32)
+        for it in np.argsort(-loads, kind="stable"):
+            b = int(np.argmin(bin_load))
+            assign[it] = b
+            bin_load[b] += int(loads[it])
+        return assign
+
+    if balance == "lpt":
+        w_col = lpt(np.bincount(word, minlength=corpus.num_words), cols)
+        d_row = lpt(np.bincount(doc, minlength=corpus.num_docs), rows)
+    else:
+        w_col = (_hash(np.arange(corpus.num_words)) % cols).astype(np.int32)
+        d_row = (_hash(np.arange(corpus.num_docs), 17) % rows).astype(np.int32)
+
+    def relabel(assign, bins):
+        per = int(np.bincount(assign, minlength=bins).max())
+        perm = np.empty(assign.shape[0], dtype=np.int64)
+        for b in range(bins):
+            ids = np.where(assign == b)[0]
+            perm[ids] = b * per + np.arange(ids.size)
+        return perm, per
+
+    word_perm, wps = relabel(w_col, cols)
+    doc_perm, dps = relabel(d_row, rows)
+    new_word, new_doc = word_perm[word], doc_perm[doc]
+    cell = (new_doc // dps) * cols + new_word // wps
+    cells = rows * cols
+    e_cell = int(np.bincount(cell, minlength=cells).max())
+    e_cell = max(-(-e_cell // 8) * 8, 8)
+    out_w = np.zeros((cells, e_cell), dtype=np.int32)
+    out_d = np.zeros((cells, e_cell), dtype=np.int32)
+    out_m = np.zeros((cells, e_cell), dtype=bool)
+    order = np.lexsort((new_doc, new_word, cell) if sort_tokens_by == "word"
+                       else (new_word, new_doc, cell))
+    sw, sd, sc = new_word[order], new_doc[order], cell[order]
+    for c in range(cells):
+        lo, hi = np.searchsorted(sc, c), np.searchsorted(sc, c + 1)
+        out_w[c, :hi - lo], out_d[c, :hi - lo] = sw[lo:hi], sd[lo:hi]
+        out_m[c, :hi - lo] = True
+        r, cc = divmod(c, cols)
+        out_w[c, hi - lo:], out_d[c, hi - lo:] = cc * wps, r * dps
+    return out_w, out_d, out_m, word_perm, doc_perm, wps, dps
+
+
+def _sparse_corpus():
+    """A skewed corpus whose vocabulary and document ids have gaps: words
+    and documents with no token, at both ends and inside."""
+    from repro.core.types import Corpus
+
+    rng = np.random.default_rng(3)
+    n = 3000
+    word = np.minimum(rng.zipf(1.3, n), 200).astype(np.int32) * 2 + 5
+    doc = rng.integers(0, 60, n).astype(np.int32) * 3 + 1
+    return Corpus(word=word, doc=doc, num_words=420, num_docs=190)
+
+
+@pytest.mark.parametrize("grid_shape", [(1, 4), (2, 2), (4, 1)])
+@pytest.mark.parametrize("balance", ["lpt", "hash"])
+@pytest.mark.parametrize("order", ["word", "doc"])
+@pytest.mark.parametrize("which", ["skewed", "sparse"])
+def test_grid_partition_equals_the_plain_algorithm(skewed, grid_shape,
+                                                   balance, order, which):
+    corpus = skewed[0] if which == "skewed" else _sparse_corpus()
+    rows, cols = grid_shape
+    g = grid_partition(corpus, rows, cols, balance=balance,
+                       sort_tokens_by=order)
+    want = _oracle_grid(corpus, rows, cols, balance, order)
+    got = (g.word, g.doc, g.mask, g.word_perm, g.doc_perm,
+           g.words_per_shard, g.docs_per_shard)
+    for name, a, b in zip(("word", "doc", "mask", "word_perm", "doc_perm",
+                           "words_per_shard", "docs_per_shard"), got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    assert g.num_words == corpus.num_words

@@ -85,8 +85,8 @@ def single_box_state(key):
                     stale_iters=zeros, same_count=zeros)
 
 # ONE evaluator for both paths: the mesh state's counts mapped back to
-# corpus ids (the dist llh uses the padded vocab in W*beta, so comparing
-# raw dist llh against the single-box llh would mix two metrics)
+# corpus ids, scored by the single-box llh (the dist llh has the same W in
+# W*beta, but sums per cell, in another order)
 from repro.core.likelihood import predictive_llh
 
 def eval_dist(dist_state):
@@ -122,7 +122,7 @@ state, data = init_dist_state(jax.random.key(0), mesh, grid, hyper,
 dcfg = resolve_dist_row_pads(state,
                              DistConfig(algorithm=alg, max_kd=0, max_kw=0))
 step = make_dist_step(mesh, hyper, dcfg, grid.words_per_shard,
-                      grid.docs_per_shard)
+                      grid.docs_per_shard, grid.num_words)
 l0 = eval_dist(state)
 mesh_llhs = [l0]
 st = state
@@ -193,7 +193,7 @@ state, data = init_dist_state(jax.random.key(0), mesh, grid, hyper,
                               init_topics=init_grid)
 dcfg = DistConfig(algorithm=alg)
 step = make_dist_step(mesh, hyper, dcfg, grid.words_per_shard,
-                      grid.docs_per_shard)
+                      grid.docs_per_shard, grid.num_words)
 knobs = backend.resolve_cell_knobs(dcfg.knobs(), hyper)
 
 rows, cols = 1, 2
@@ -216,7 +216,7 @@ for row in range(rows):
         n_kd_l = jnp.asarray(n_kd0[row * dps:(row + 1) * dps])
         z_prop = backend.cell_sweep(
             k_sample, word_l, doc_l, z_old, mask, n_wk_l, n_kd_l,
-            jnp.asarray(n_k0), hyper, grid.num_words_padded, knobs)
+            jnp.asarray(n_k0), hyper, grid.num_words, knobs)
         z_new = np.where(np.asarray(mask), np.asarray(z_prop),
                          np.asarray(z_old))
         live = np.asarray(mask)
